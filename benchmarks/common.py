@@ -9,6 +9,10 @@ from typing import Dict, List, Optional, Union
 import jax
 import numpy as np
 
+from repro.utils import enable_compile_cache
+
+enable_compile_cache()
+
 # benchmark-scale knob: FULL=1 uses the paper's grid sizes (ATM 1800x3600);
 # default runs reduced grids so the suite finishes quickly on 1 CPU core.
 FULL = os.environ.get("REPRO_BENCH_FULL", "0") == "1"

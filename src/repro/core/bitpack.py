@@ -122,22 +122,24 @@ def local_pack_bytes(mags: jnp.ndarray, widths: jnp.ndarray,
     are exactly its slice of the :func:`pack_blocks` stream; the tail is 0.
     Per-block independent (no global searchsorted), so the work is
     ``B*ceil(K*w/8)`` bytes instead of the 32-bit worst-case capacity.
+    Built one bit plane at a time: a (B, NBM, 8) intermediate would be
+    laid out with its minor 8 padded to 128 lanes on the TPU (16x).
     This is the jnp oracle for ``kernels/bitpack_pack.py``.
     """
     mags = mags.astype(jnp.uint32)
     b_blocks, k = mags.shape
     nbm = (k * max_width + 7) // 8
-    w = widths.astype(jnp.int32)[:, None, None]             # (B, 1, 1)
-    t = (jnp.arange(nbm, dtype=jnp.int32)[:, None] * 8
-         + jnp.arange(8, dtype=jnp.int32)[None, :])[None]   # (1, nbm, 8)
+    w = widths.astype(jnp.int32)[:, None]                   # (B, 1)
     w_safe = jnp.maximum(w, 1)
-    i = jnp.minimum(t // w_safe, k - 1)                     # value index
-    bit_in_val = (t % w_safe).astype(jnp.uint32)
-    vals = jnp.take_along_axis(mags, i.reshape(b_blocks, nbm * 8), axis=1)
-    bits = (vals.reshape(b_blocks, nbm, 8) >> bit_in_val) & jnp.uint32(1)
-    valid = (t < k * w) & (w > 0)
-    bits = jnp.where(valid, bits, jnp.uint32(0))
-    byte = (bits << jnp.arange(8, dtype=jnp.uint32)).sum(axis=2)
+    j8 = 8 * jnp.arange(nbm, dtype=jnp.int32)[None, :]      # (1, nbm)
+    byte = jnp.zeros((b_blocks, nbm), jnp.uint32)
+    for bit in range(8):
+        t = j8 + bit                                        # bit in block
+        i = jnp.minimum(t // w_safe, k - 1)                 # value index
+        vals = jnp.take_along_axis(mags, i, axis=1)
+        bits = (vals >> (t % w_safe).astype(jnp.uint32)) & jnp.uint32(1)
+        valid = (t < k * w) & (w > 0)
+        byte = byte | (jnp.where(valid, bits, jnp.uint32(0)) << bit)
     return byte.astype(jnp.uint8)
 
 
@@ -174,24 +176,29 @@ def pack_blocks_tiled(mags: jnp.ndarray, widths: jnp.ndarray,
 
 
 def unpack_blocks(buf: jnp.ndarray, widths: jnp.ndarray, k: int) -> jnp.ndarray:
-    """Inverse of :func:`pack_blocks` -> (B, K) uint32 magnitudes."""
+    """Inverse of :func:`pack_blocks` -> (B, K) uint32 magnitudes.
+
+    Every value reads the <= 5 bytes its bit window spans with five 1-D
+    gathers over the flat (B*K,) value index.  (One (B, K, 5) gather would
+    be laid out with its minor 5 padded to a full 128-lane tile on the
+    TPU: 25x the bytes.)"""
     b_blocks = widths.shape[0]
     nb = block_nbytes(widths, k)
     offs = exclusive_cumsum(nb)
 
-    w = widths[:, None]                                 # (B, 1)
-    i = jnp.arange(k, dtype=jnp.int32)[None, :]         # (1, K)
-    s = i * w                                           # bit start inside block
-    byte0 = offs[:, None] + s // 8                      # absolute first byte
+    w = widths.astype(jnp.int32)[:, None]               # (B, 1)
+    s = jnp.arange(k, dtype=jnp.int32)[None, :] * w     # bit start in block
+    byte0 = (offs[:, None] + s // 8).reshape(-1)        # absolute first byte
     sh = (s % 8).astype(jnp.uint32)
 
     cap = buf.shape[0]
-    idx = byte0[:, :, None] + jnp.arange(5, dtype=jnp.int32)[None, None, :]
-    idx = jnp.clip(idx, 0, cap - 1)
-    bts = buf[idx].astype(jnp.uint32)                   # (B, K, 5)
 
-    lo = bts[..., 0] | (bts[..., 1] << 8) | (bts[..., 2] << 16) | (bts[..., 3] << 24)
-    hi = bts[..., 4]
+    def byte(j):
+        idx = jnp.clip(byte0 + j, 0, cap - 1)
+        return buf[idx].astype(jnp.uint32).reshape(b_blocks, k)
+
+    lo = byte(0) | (byte(1) << 8) | (byte(2) << 16) | (byte(3) << 24)
+    hi = byte(4)
     # value = (lo >> sh) | (hi << (32 - sh)), guarding the sh == 0 case
     # (shifting a uint32 by 32 is undefined in XLA).
     up = jnp.where(sh == 0, jnp.uint32(0), hi << (jnp.uint32(32) - sh))
@@ -206,34 +213,39 @@ def unpack_blocks(buf: jnp.ndarray, widths: jnp.ndarray, k: int) -> jnp.ndarray:
 
 
 # ---- fixed-width helpers (sign bits, 2-bit label maps) ---------------------
+# Built from strided bit planes and 1-D gathers: an (n/8, 8) or (n/4, 4)
+# intermediate would be laid out on the TPU with its minor 8 or 4 padded
+# to 128 lanes (16-32x the bytes; 18 GB for one 283M-element gradient).
 
 def pack_bits(bits: jnp.ndarray) -> jnp.ndarray:
     """Pack a flat {0,1} array into uint8 bytes (little-endian bit order)."""
     n = bits.shape[0]
-    pad = (-n) % 8
-    b = jnp.pad(bits.astype(jnp.uint32), (0, pad)).reshape(-1, 8)
-    return (b << jnp.arange(8, dtype=jnp.uint32)[None, :]).sum(axis=1) \
-        .astype(jnp.uint8)
+    b = jnp.pad(bits.astype(jnp.uint32), (0, (-n) % 8))
+    out = b[0::8]
+    for j in range(1, 8):
+        out = out | (b[j::8] << j)
+    return out.astype(jnp.uint8)
 
 
 def unpack_bits(buf: jnp.ndarray, n: int) -> jnp.ndarray:
     """Inverse of :func:`pack_bits`; returns (n,) uint8 of {0,1}."""
-    bits = (buf[:, None].astype(jnp.uint32)
-            >> jnp.arange(8, dtype=jnp.uint32)[None, :]) & 1
-    return bits.reshape(-1)[:n].astype(jnp.uint8)
+    i = jnp.arange(n, dtype=jnp.int32)
+    byte = buf[i >> 3].astype(jnp.uint32)
+    return ((byte >> (i & 7).astype(jnp.uint32)) & 1).astype(jnp.uint8)
 
 
 def pack_2bit(vals: jnp.ndarray) -> jnp.ndarray:
     """Pack a flat array of 2-bit codes (0..3) into bytes, 4 per byte."""
     n = vals.shape[0]
-    pad = (-n) % 4
-    v = jnp.pad(vals.astype(jnp.uint32), (0, pad)).reshape(-1, 4)
-    return (v << (2 * jnp.arange(4, dtype=jnp.uint32))[None, :]).sum(axis=1) \
-        .astype(jnp.uint8)
+    v = jnp.pad(vals.astype(jnp.uint32), (0, (-n) % 4))
+    out = v[0::4]
+    for j in range(1, 4):
+        out = out | (v[j::4] << (2 * j))
+    return out.astype(jnp.uint8)
 
 
 def unpack_2bit(buf: jnp.ndarray, n: int) -> jnp.ndarray:
     """Inverse of :func:`pack_2bit`; returns (n,) int32 codes in 0..3."""
-    v = (buf[:, None].astype(jnp.uint32)
-         >> (2 * jnp.arange(4, dtype=jnp.uint32))[None, :]) & 3
-    return v.reshape(-1)[:n].astype(jnp.int32)
+    i = jnp.arange(n, dtype=jnp.int32)
+    byte = buf[i >> 2].astype(jnp.uint32)
+    return ((byte >> (2 * (i & 3)).astype(jnp.uint32)) & 3).astype(jnp.int32)

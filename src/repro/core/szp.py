@@ -141,13 +141,29 @@ def _unpack_sections(parts: SZpParts, block: int):
 # Float pipeline: backend-threaded two-pass compress / guarded decompress
 # --------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("block", "backend"))
-def _quant_stage(x: jnp.ndarray, eb: float, block: int, backend: str):
+def _quant_fn(x: jnp.ndarray, eb: float, block: int, backend: str):
     """Pass 1: fused QZ+LZ through kernels.ops + measured max width."""
     with jax.named_scope("szp.stage_quant"):
         xb = _blocked_field(x, block)
         first, mags, signs, widths = ops.szp_quant(xb, eb, backend=backend)
         return first, mags, signs, widths, widths.max()
+
+
+def _quant_batch_fn(xs: jnp.ndarray, eb: float, block: int, backend: str):
+    """Batched pass 1; the width max is reduced over the WHOLE batch
+    in-graph, so the caller's bucket decision reads one device scalar
+    instead of N per-field maxes."""
+    first, mags, signs, widths, w_max = jax.vmap(
+        lambda x: _quant_fn(x, eb, block, backend))(xs)
+    return first, mags, signs, widths, w_max.max()
+
+
+# Pass 1 is one program per (shape, backend), shared by the classic and
+# the resident compress.
+_quant_stage, _quant_stage_donated, _quant_stage_batch, \
+    _quant_stage_batch_donated = (
+        jax.jit(fn, static_argnames=("block", "backend"), donate_argnums=don)
+        for fn in (_quant_fn, _quant_batch_fn) for don in ((), (0,)))
 
 
 @functools.partial(jax.jit, static_argnames=("max_width", "backend"))
@@ -224,23 +240,26 @@ def _pack_switch(streams, block: int, backend: str,
                           tuple(streams))
 
 
-def _compress_resident(x: jnp.ndarray, eb, block: int,
-                       backend: str) -> SZpParts:
-    """Device-resident compress: quant + bucket select + pack, no host."""
-    with jax.named_scope("szp.stage_quant"):
-        xb = _blocked_field(x, block)
-        first, mags, signs, widths = ops.szp_quant(xb, eb, backend=backend)
+@functools.partial(jax.jit, static_argnames=("block", "backend", "batched"))
+def _pack_resident(first, mags, signs, widths, block: int, backend: str,
+                   batched: bool = False) -> SZpParts:
+    """Pass 2 on device: bucket select + BE pack (batched, the bucket
+    switch sits outside the vmap: one shared bucket for the batch, a real
+    branch instead of a both-sides ``select``)."""
     with jax.named_scope("szp.stage_pack"):
         (parts,) = _pack_switch(((first, mags, signs, widths),), block,
-                                backend)
+                                backend, batched=batched)
     return parts
 
 
-_compress_resident_jit = jax.jit(
-    _compress_resident, static_argnames=("block", "backend"))
-_compress_resident_donated = jax.jit(
-    _compress_resident, static_argnames=("block", "backend"),
-    donate_argnums=(0,))
+def _compress_resident(quant, x, eb, block: int, backend: str,
+                       batched: bool) -> SZpParts:
+    """Device-resident compress: pass 1 + on-device pass 2, no host
+    syncs; composes under an enclosing ``jax.jit``."""
+    first, mags, signs, widths, _ = quant(x, eb, block=block,
+                                          backend=backend)
+    return _pack_resident(first, mags, signs, widths, block=block,
+                          backend=backend, batched=batched)
 
 
 @contextlib.contextmanager
@@ -273,18 +292,15 @@ def szp_compress(x: jnp.ndarray, eb, block: int = DEFAULT_BLOCK,
     backend = ops.resolve_backend(backend)
     if resident:
         with obs.span("compress.resident", pipeline="szp", backend=backend):
-            if donate:
-                with _quiet_donation():
-                    parts = _compress_resident_donated(x, eb, block=block,
-                                                       backend=backend)
-            else:
-                parts = _compress_resident_jit(x, eb, block=block,
-                                               backend=backend)
+            with _quiet_donation():
+                parts = _compress_resident(
+                    _quant_stage_donated if donate else _quant_stage,
+                    x, eb, block, backend, batched=False)
         _obs_stream(parts, "szp", "resident")
         return parts
     with obs.span("compress.quant", pipeline="szp", backend=backend):
-        first, mags, signs, widths, w_max = _quant_stage(x, eb, block,
-                                                         backend)
+        first, mags, signs, widths, w_max = _quant_stage(
+            x, eb, block=block, backend=backend)
         mw = bitpack.width_bucket(int(w_max))   # the existing sync point
     with obs.span("compress.pack", pipeline="szp", width_bucket=mw):
         parts = _pack_stage(first, mags, signs, widths, mw, backend)
@@ -368,41 +384,11 @@ def _dequant_backend_for(parts: SZpParts, block: int, backend: str) -> str:
     return backend
 
 
-@functools.partial(jax.jit, static_argnames=("block", "backend"))
-def _quant_stage_batch(xs: jnp.ndarray, eb: float, block: int, backend: str):
-    """Batched pass 1; the width max is reduced over the WHOLE batch
-    in-graph, so the caller's bucket decision reads one device scalar
-    instead of N per-field maxes."""
-    first, mags, signs, widths, w_max = jax.vmap(
-        lambda x: _quant_stage(x, eb, block, backend))(xs)
-    return first, mags, signs, widths, w_max.max()
-
-
 @functools.partial(jax.jit, static_argnames=("max_width", "backend"))
 def _pack_stage_batch(first, mags, signs, widths, max_width: int,
                       backend: str) -> SZpParts:
     return jax.vmap(lambda f, m, s, w: _assemble_parts(
         f, m, s, w, max_width, backend=backend))(first, mags, signs, widths)
-
-
-def _compress_resident_batch(xs: jnp.ndarray, eb, block: int,
-                             backend: str) -> SZpParts:
-    """Batched device-resident compress: the bucket switch sits OUTSIDE
-    the vmap (one shared bucket for the whole batch, same semantics as the
-    classic batched pack), so it stays a real branch instead of a
-    both-sides ``select``."""
-    first, mags, signs, widths, _ = jax.vmap(
-        lambda x: _quant_stage(x, eb, block, backend))(xs)
-    (parts,) = _pack_switch(((first, mags, signs, widths),), block, backend,
-                            batched=True)
-    return parts
-
-
-_compress_resident_batch_jit = jax.jit(
-    _compress_resident_batch, static_argnames=("block", "backend"))
-_compress_resident_batch_donated = jax.jit(
-    _compress_resident_batch, static_argnames=("block", "backend"),
-    donate_argnums=(0,))
 
 
 def szp_compress_batch(xs: jnp.ndarray, eb,
@@ -423,13 +409,11 @@ def szp_compress_batch(xs: jnp.ndarray, eb,
     if resident:
         with obs.span("compress.resident", pipeline="szp", backend=backend,
                       batch=xs.shape[0]):
-            if donate:
-                with _quiet_donation():
-                    parts = _compress_resident_batch_donated(
-                        xs, eb, block=block, backend=backend)
-            else:
-                parts = _compress_resident_batch_jit(xs, eb, block=block,
-                                                     backend=backend)
+            with _quiet_donation():
+                parts = _compress_resident(
+                    _quant_stage_batch_donated if donate
+                    else _quant_stage_batch,
+                    xs, eb, block, backend, batched=True)
         _obs_stream(parts, "szp", "resident")
         return parts
     with obs.span("compress.quant", pipeline="szp", backend=backend,

@@ -18,8 +18,8 @@ Stream bytes are bit-identical across all backends.  The rank stream
 int32-cumsum path regardless of backend.
 
 ``toposzp_compress_batch`` / ``toposzp_decompress_batch`` stack N
-same-shape fields into ONE compiled call (vmap = grid over the batch dim),
-so multi-field workloads (checkpoint shards, the fig7 bench) stop paying a
+same-shape fields into ONE compiled call (compress: vmap = grid over the
+batch dim; decompress: a loop over the fields), so multi-field workloads (checkpoint shards, the fig7 bench) stop paying a
 dispatch + trace per field.
 """
 from __future__ import annotations
@@ -121,18 +121,22 @@ def _compress_measure(field: jnp.ndarray, eb: float, block: int,
             labels2b, n_cp, widths.max(), rwidths.max())
 
 
-_measure_one = jax.jit(_compress_measure,
-                       static_argnames=("block", "backend"))
-
-
-@functools.partial(jax.jit, static_argnames=("block", "backend"))
-def _measure_batch(fields: jnp.ndarray, eb: float, block: int, backend: str):
+def _compress_measure_batch(fields: jnp.ndarray, eb: float, block: int,
+                            backend: str):
     """Batched pass 1; both width maxes are reduced over the WHOLE batch
     in-graph so the caller's bucket decision reads one scalar pair
     instead of N per-field maxes."""
     main, rank, labels2b, n_cp, w_max, rw_max = jax.vmap(
         lambda f: _compress_measure(f, eb, block, backend))(fields)
     return main, rank, labels2b, n_cp, w_max.max(), rw_max.max()
+
+
+# Pass 1 is one program per (shape, backend), shared by the classic and
+# the resident compress (its rank sort dominates the compile time).
+_measure_one, _measure_one_donated, _measure_batch, _measure_batch_donated = (
+    jax.jit(fn, static_argnames=("block", "backend"), donate_argnums=don)
+    for fn in (_compress_measure, _compress_measure_batch)
+    for don in ((), (0,)))
 
 
 @functools.partial(jax.jit, static_argnames=("block", "mw_main", "mw_rank",
@@ -158,48 +162,32 @@ def _pack_streams(main, rank, labels2b, n_cp, block: int, mw_main: int,
                              nbytes.astype(jnp.int32))
 
 
-def _compress_resident_topo(field: jnp.ndarray, eb, block: int,
-                            backend: str) -> TopoSZpCompressed:
-    """Device-resident TopoSZp compress: measure + shared-bucket switch
-    pack, no host syncs.  Main and rank streams are packed at the SHARED
-    bucket of their joint max width (6 ``lax.switch`` branches instead of
-    36 bucket pairs); valid bytes and the serialized stream are identical
-    to the per-stream-bucket classic pack."""
-    main, rank, labels2b, n_cp, _, _ = _compress_measure(
-        field, eb, block, backend)
-    with jax.named_scope("toposzp.stage_pack"):
-        szp_parts, rank_parts = _pack_switch((main, rank), block, backend)
-    nbytes = (szp_parts.nbytes + labels2b.shape[0]
-              + rank_stream_bytes(n_cp, rank_parts.payload_nbytes, block))
-    return TopoSZpCompressed(szp_parts, labels2b, rank_parts, n_cp,
-                             nbytes.astype(jnp.int32))
-
-
-def _compress_resident_topo_batch(fields: jnp.ndarray, eb, block: int,
-                                  backend: str) -> TopoSZpCompressed:
-    """Batched device-resident TopoSZp compress (bucket switch hoisted
-    outside the vmap; one shared bucket for the whole batch)."""
-    main, rank, labels2b, n_cp, _, _ = jax.vmap(
-        lambda f: _compress_measure(f, eb, block, backend))(fields)
+@functools.partial(jax.jit, static_argnames=("block", "backend", "batched"))
+def _pack_resident(main, rank, labels2b, n_cp, block: int, backend: str,
+                   batched: bool = False) -> TopoSZpCompressed:
+    """Pass 2 on device: shared-bucket switch pack, no host syncs.  Main
+    and rank streams are packed at the SHARED bucket of their joint max
+    width (6 ``lax.switch`` branches instead of 36 bucket pairs; batched,
+    the switch sits outside the vmap with one bucket for the batch);
+    valid bytes and the serialized stream are identical to the
+    per-stream-bucket classic pack."""
     with jax.named_scope("toposzp.stage_pack"):
         szp_parts, rank_parts = _pack_switch((main, rank), block, backend,
-                                             batched=True)
-    nbytes = (szp_parts.nbytes + labels2b.shape[1]
+                                             batched=batched)
+    nbytes = (szp_parts.nbytes + labels2b.shape[-1]
               + rank_stream_bytes(n_cp, rank_parts.payload_nbytes, block))
     return TopoSZpCompressed(szp_parts, labels2b, rank_parts, n_cp,
                              nbytes.astype(jnp.int32))
 
 
-_topo_resident_jit = jax.jit(
-    _compress_resident_topo, static_argnames=("block", "backend"))
-_topo_resident_donated = jax.jit(
-    _compress_resident_topo, static_argnames=("block", "backend"),
-    donate_argnums=(0,))
-_topo_resident_batch_jit = jax.jit(
-    _compress_resident_topo_batch, static_argnames=("block", "backend"))
-_topo_resident_batch_donated = jax.jit(
-    _compress_resident_topo_batch, static_argnames=("block", "backend"),
-    donate_argnums=(0,))
+def _compress_resident(measure, fields, eb, block: int, backend: str,
+                       batched: bool) -> TopoSZpCompressed:
+    """Device-resident compress: pass 1 + on-device pass 2, no host
+    syncs; composes under an enclosing ``jax.jit``."""
+    main, rank, labels2b, n_cp, _, _ = measure(fields, eb, block=block,
+                                               backend=backend)
+    return _pack_resident(main, rank, labels2b, n_cp, block=block,
+                          backend=backend, batched=batched)
 
 
 def _obs_topo_stream(comp: TopoSZpCompressed, mode: str) -> None:
@@ -238,13 +226,10 @@ def toposzp_compress(field: jnp.ndarray, eb,
     if resident:
         with obs.span("compress.resident", pipeline="toposzp",
                       backend=backend):
-            if donate:
-                with _quiet_donation():
-                    comp = _topo_resident_donated(field, eb, block=block,
-                                                  backend=backend)
-            else:
-                comp = _topo_resident_jit(field, eb, block=block,
-                                          backend=backend)
+            with _quiet_donation():
+                comp = _compress_resident(
+                    _measure_one_donated if donate else _measure_one,
+                    field, eb, block, backend, batched=False)
         _obs_topo_stream(comp, "resident")
         return comp
     with obs.span("compress.quant", pipeline="toposzp", backend=backend,
@@ -287,13 +272,10 @@ def toposzp_compress_batch(fields: jnp.ndarray, eb,
     if resident:
         with obs.span("compress.resident", pipeline="toposzp",
                       backend=backend, batch=fields.shape[0]):
-            if donate:
-                with _quiet_donation():
-                    comp = _topo_resident_batch_donated(
-                        fields, eb, block=block, backend=backend)
-            else:
-                comp = _topo_resident_batch_jit(fields, eb, block=block,
-                                                backend=backend)
+            with _quiet_donation():
+                comp = _compress_resident(
+                    _measure_batch_donated if donate else _measure_batch,
+                    fields, eb, block, backend, batched=True)
         _obs_topo_stream(comp, "resident")
         return comp
     with obs.span("compress.quant", pipeline="toposzp", backend=backend,
@@ -414,14 +396,17 @@ def _decompress_one(comp, eb, shape, block, rbf_mode, recon, backend):
                                              "recon", "backend"))
 def _decompress_batch(comp, eb, shape, block, rbf_mode, recon, backend):
     """Batched decompress; the dequant guard ``lax.cond`` is hoisted
-    OUTSIDE the vmap (scalar max over the whole batch's widths) — under
-    vmap a cond lowers to ``select`` and executes both branches."""
+    OUTSIDE the per-field loop (scalar max over the whole batch's widths).
+    Fields decode one after another (``lax.map``), not under ``vmap``: a
+    vmapped FP/FT suppression ``while_loop`` carries the batch axis, and
+    the TPU lays it out as the minor dimension — a 16x-padded copy of
+    every carried field."""
     def run(deq_backend):
         def one(c):
             base, labels, ranks = _decode_field(c, shape, eb, block, recon,
                                                 deq_backend, backend)
             return _restore_field(base, labels, ranks, eb, rbf_mode, backend)
-        return lambda cb: jax.vmap(one)(cb)
+        return lambda cb: jax.lax.map(one, cb)
     if backend == "jnp":
         return run("jnp")(comp)
     overflow = (comp.szp.widths.astype(jnp.int32).max()

@@ -6,9 +6,8 @@
 ``dist.ring``        — bitpacked ppermute ring all-reduce (the "packed"
                        wire format: actual compressed bytes on the wire)
 ``dist.elastic``     — largest-valid-mesh rebuild after device loss
-``dist.compat``      — shard_map shim across JAX versions
 """
-from repro.dist import collectives, compat, elastic, ring, sharding
+from repro.dist import collectives, elastic, ring, sharding
 from repro.dist.collectives import (WIRE_FORMATS, code_bits,
                                     compressed_psum_tree, max_code,
                                     protect_k, quantize_dequantize_sum,
@@ -16,7 +15,6 @@ from repro.dist.collectives import (WIRE_FORMATS, code_bits,
                                     topo_compressed_psum_tree,
                                     topo_quantize_dequantize_sum,
                                     topo_wire_bits)
-from repro.dist.compat import shard_map
 from repro.dist.elastic import (DeviceLoss, largest_mesh_shape,
                                 mesh_shape_dict, rebuild_mesh)
 from repro.dist.ring import (packed_psum_tree, packed_wire_summary,
@@ -26,14 +24,14 @@ from repro.dist.sharding import (adapt_spec, batch_axes, cache_shardings,
                                  spec_from_json, spec_to_json)
 
 __all__ = [
-    "collectives", "compat", "elastic", "ring", "sharding",
+    "collectives", "elastic", "ring", "sharding",
     "WIRE_FORMATS", "code_bits", "compressed_psum_tree", "max_code",
     "quantize_dequantize_sum",
     "protect_k", "sidecar_bits", "topk_rank_preservation",
     "topo_compressed_psum_tree", "topo_quantize_dequantize_sum",
     "topo_wire_bits",
     "packed_psum_tree", "packed_wire_summary", "simulate_hop_bytes",
-    "shard_map", "DeviceLoss", "largest_mesh_shape", "mesh_shape_dict",
+    "DeviceLoss", "largest_mesh_shape", "mesh_shape_dict",
     "rebuild_mesh",
     "adapt_spec", "batch_axes", "cache_shardings", "data_sharding",
     "param_shardings", "replicated", "spec_from_json", "spec_to_json",

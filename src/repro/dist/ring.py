@@ -17,17 +17,18 @@ Ring schedule (single data-parallel axis, n members, n-1 hops):
     members needs at most ``base_width(rel_eb) + ceil(log2(h))`` bits
     (``bitpack.sum_width``), because ``|q| <= 1/(2 rel_eb) + 2`` holds
     deterministically — appends the sign bitplane (``pack_bits``), the
-    per-block width bytes, and the topo sidecar's fp32 values, ships the
-    single uint8 buffer with ``jax.lax.ppermute``, unpacks, and adds its
-    own codes to the received partial sum;
+    per-block width bytes, ships the uint8 buffer with
+    ``jax.lax.ppermute`` (the topo sidecar's fp32 values ride the same hop
+    as a second message), unpacks, and adds its own codes to the received
+    partial sum;
   * after n-1 hops every member holds the full integer code sum —
     bit-identical to ``jax.lax.psum`` of the codes, since integer
     addition commutes — and dequantizes once.
 
 Topo sidecar: the per-member top-k indices circulate first (an index
 pre-ring of k int32 per hop), giving every member the same member-ordered
-union; each member's exact fp32 values at EVERY union index then ride the
-packed body buffer, collected by origin.  The exact sums are folded in
+union; each member's exact fp32 values at EVERY union index then ride
+each hop beside the packed body, collected by origin.  The exact sums are folded in
 member order 0..n-1 — on the CPU/TPU ring all-reduce this matches
 ``jax.lax.psum``'s reduction order bit-for-bit, which is what makes the
 packed and int32 wire formats produce identical protected entries.
@@ -79,32 +80,6 @@ def _require_single_axis(axes: Sequence[str]) -> str:
             f"data-parallel axis; got {tuple(axes)}.  Use "
             f"wire_format='int32' on multi-axis (pod) meshes.")
     return axes[0]
-
-
-# --------------------------------------------------------------------------
-# byte views (version-portable: shifts, not narrowing bitcasts)
-# --------------------------------------------------------------------------
-
-def _u32_to_bytes(x: jnp.ndarray) -> jnp.ndarray:
-    """(m,) uint32/int32 -> (4m,) uint8, little-endian."""
-    x = x.astype(jnp.uint32)
-    sh = (jnp.arange(4, dtype=jnp.uint32) * 8)[None, :]
-    return ((x[:, None] >> sh) & jnp.uint32(0xFF)).astype(jnp.uint8).reshape(-1)
-
-
-def _bytes_to_u32(b: jnp.ndarray) -> jnp.ndarray:
-    """(4m,) uint8 -> (m,) uint32, little-endian."""
-    b = b.reshape(-1, 4).astype(jnp.uint32)
-    sh = (jnp.arange(4, dtype=jnp.uint32) * 8)[None, :]
-    return (b << sh).sum(axis=1).astype(jnp.uint32)
-
-
-def _f32_to_bytes(v: jnp.ndarray) -> jnp.ndarray:
-    return _u32_to_bytes(jax.lax.bitcast_convert_type(v, jnp.uint32))
-
-
-def _bytes_to_f32(b: jnp.ndarray) -> jnp.ndarray:
-    return jax.lax.bitcast_convert_type(_bytes_to_u32(b), jnp.float32)
 
 
 # --------------------------------------------------------------------------
@@ -200,28 +175,27 @@ def ring_allreduce_codes(
             buf, _, total = ops.compact_bytes(local, widths, block_k,
                                               backend=backend)
             signs = pack_bits((msg < 0).astype(jnp.uint32))
-            parts = [buf, signs, widths.astype(jnp.uint8)]
-            if vmsg is not None:
-                parts.append(_f32_to_bytes(vmsg))
-            payload = jnp.concatenate(parts)
+            payload = jnp.concatenate([buf, signs, widths.astype(jnp.uint8)])
             valid = valid + (total.astype(jnp.float32)
                              + jnp.float32(sign_bytes + b_blocks + 4 * u))
 
             payload = jax.lax.ppermute(payload, axis, perm)
+            if vmsg is not None:
+                # fp32 values travel as their own message: spliced into
+                # the uint8 body as bytes, they made the TPU compile of a
+                # hop take minutes at MiniCPM-2B leaf sizes
+                vmsg = jax.lax.ppermute(vmsg, axis, perm)
+                vout = vout.at[(i - h) % n].set(vmsg)
 
             o_sign = mag_cap
             o_width = o_sign + sign_bytes
-            o_val = o_width + b_blocks
-            rwidths = payload[o_width:o_val].astype(jnp.int32)
+            rwidths = payload[o_width:o_width + b_blocks].astype(jnp.int32)
             rmags = unpack_blocks(payload[:mag_cap], rwidths,
                                   block_k).reshape(-1)
             rsigns = unpack_bits(payload[o_sign:o_width], p)
             rcodes = jnp.where(rsigns == 1, -rmags.astype(jnp.int32),
                                rmags.astype(jnp.int32))
             msg = rcodes + q                  # received h members + own
-            if vmsg is not None:
-                vmsg = _bytes_to_f32(payload[o_val:o_val + 4 * u])
-                vout = vout.at[(i - h) % n].set(vmsg)
     return msg, vout, valid
 
 
@@ -276,7 +250,7 @@ def packed_psum_tree(grads: Any, axes: Sequence[str], rel_eb: float,
     backends) as ``collectives._psum_tree(wire_format="int32")``: returns
     ``(mean gradient tree, new error-feedback tree)``.  Leaves are
     concatenated into buckets so small leaves share one packed stream per
-    hop; the topo sidecar rides the body buffer (see module docstring).
+    hop; the topo sidecar rides each hop beside it (see module docstring).
     """
     axis = _require_single_axis(tuple(axes))
     n = _axis_size((axis,))

@@ -25,6 +25,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 DEFAULT_TB = 256  # blocks per grid instance
+# The K-step unrolled body keeps ~0.64*K (TB, NBM) int32 temporaries alive
+# in VMEM (measured: 64 MB at K=256, TB=256, NBM=384); keep them within a
+# budget under the 16 MB scoped-VMEM default.
+_VMEM_BUDGET = 12 << 20
+
+
+def tile_rows(k: int, max_width: int, tb: int = DEFAULT_TB) -> int:
+    """Rows per grid instance: ``tb``, cut (in steps of the 32-row uint8
+    tile) until the unrolled body's temporaries fit the VMEM budget."""
+    nbm = (k * max_width + 7) // 8
+    fit = _VMEM_BUDGET // max(1, (64 * k // 100) * nbm * 4)
+    return max(32, min(tb, fit // 32 * 32))
 
 
 def _make_pack_kernel(k: int, nbm: int):
@@ -49,7 +61,7 @@ def _make_pack_kernel(k: int, nbm: int):
 @functools.partial(jax.jit, static_argnames=("max_width", "tb", "interpret"))
 def local_pack_blocks(mags: jnp.ndarray, widths: jnp.ndarray,
                       max_width: int = 32, tb: int = DEFAULT_TB,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool = False) -> jnp.ndarray:
     """Per-block local pack -> (B, ceil(K*max_width/8)) uint8.
 
     Block b's first ``ceil(K*widths[b]/8)`` bytes equal its slice of the

@@ -1,16 +1,16 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Every op takes ``backend=`` with three settings:
-  * "pallas"     — pl.pallas_call compiled for TPU (the production path);
-                   off-TPU it transparently downgrades to "interpret" so
-                   the same call sites work in the CPU container
+  * "pallas"     — pl.pallas_call compiled by Mosaic for the TPU (the
+                   production path); asking for it without a TPU raises
   * "interpret"  — same kernel body, interpreted on CPU (validation path)
   * "jnp"        — the pure-jnp oracle from kernels/ref.py
 
-``resolve_backend(None)`` picks the production default for the current
-hardware ("pallas" on TPU, "jnp" elsewhere — interpret mode is a
-validation tool, far too slow to be a CPU production path) and honors the
-``REPRO_KERNEL_BACKEND`` env override (the CI oracle leg forces "jnp").
+``backend=None`` (every op's default) or "auto" resolves to the
+production default for the current hardware ("pallas" on TPU, "jnp"
+elsewhere — interpret mode is a validation tool, far too slow to be a CPU
+production path) and honors the ``REPRO_KERNEL_BACKEND`` env override
+(the CI oracle leg forces "jnp").
 
 Wrappers own all padding/unpadding so callers see natural shapes.  Row
 padding follows ONE rule (``_row_tile``): the tile is capped at the padded
@@ -22,6 +22,7 @@ handed odd, non-sublane-aligned tiles like 100 or 129 to the kernel).
 from __future__ import annotations
 
 import os
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,58 +34,68 @@ from repro.kernels import extrema_restore as _exk
 from repro.kernels import rbf_refine as _rbk
 from repro.kernels import szp_quant as _sqk
 from repro.kernels import ref as _ref
+from repro.core.quantize import quantize
 from repro.utils import cdiv, pad_to_multiple
 
-DEFAULT_BACKEND = "interpret"
 BACKENDS = ("pallas", "interpret", "jnp")
 _ENV_BACKEND = "REPRO_KERNEL_BACKEND"
 
 
-def resolve_backend(backend=None) -> str:
-    """Resolve a backend knob ('auto'/None -> hardware default) and
-    downgrade "pallas" to "interpret" when no TPU is attached."""
+def resolve_backend(backend: Optional[str] = None) -> str:
+    """Resolve a backend knob ('auto'/None -> hardware default).
+
+    An explicit "pallas" without a TPU raises: compiled kernels never
+    silently turn into the interpreter."""
     if backend in (None, "auto"):
         backend = os.environ.get(_ENV_BACKEND) or (
             "pallas" if jax.default_backend() == "tpu" else "jnp")
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}")
     if backend == "pallas" and jax.default_backend() != "tpu":
-        return "interpret"
+        raise ValueError(
+            f"backend='pallas' needs a TPU; JAX's default backend is "
+            f"{jax.default_backend()!r} (use 'interpret' or 'jnp')")
     return backend
 
 
 def _interp(backend: str) -> bool:
-    """interpret= flag for a *resolved* backend ("pallas" implies TPU)."""
-    return backend != "pallas"
+    """interpret= flag for a *resolved* backend."""
+    return backend == "interpret"
 
 
-def _row_tile(b: int, tb: int) -> int:
-    """The shared pad-to-tile rule: tile rows = min(tb, ceil(b/8)*8)."""
-    return min(tb, max(8, cdiv(b, 8) * 8))
+def _row_tile(b: int, tb: int, align: int = 8) -> int:
+    """The shared pad-to-tile rule: tile rows = min(tb, ceil(b/a)*a).
+
+    ``align`` is the sublane tile of the kernel's narrowest operand: 8
+    for 32-bit arrays, 32 for the uint8 byte rows of the BE stage."""
+    return min(tb, max(align, cdiv(b, align) * align))
 
 
-def szp_quant(xb: jnp.ndarray, eb: float, backend: str = DEFAULT_BACKEND,
+def szp_quant(xb: jnp.ndarray, eb: float, backend: Optional[str] = None,
               tb: int = _sqk.DEFAULT_TB):
-    """Fused QZ+LZ over (B, K) blocks -> (first, mags, signs, widths)."""
+    """QZ + fused B+LZ over (B, K) blocks -> (first, mags, signs, widths).
+
+    QZ is the oracle's own XLA ``quantize`` on every backend, so the codes
+    are bit-identical; the kernel takes over from the int32 codes."""
     backend = resolve_backend(backend)
     if backend == "jnp":
         return _ref.szp_quant_blocks_ref(xb, eb)
     b = xb.shape[0]
     tb = _row_tile(b, tb)
-    xp = pad_to_multiple(xb, tb, axis=0)
-    first, mags, signs, widths = _sqk.szp_quant_blocks(
-        xp, eb, tb=tb, interpret=_interp(backend))
+    qp = pad_to_multiple(quantize(xb, eb), tb, axis=0)
+    first, mags, signs, widths = _sqk.szp_delta_blocks(
+        qp, tb=tb, interpret=_interp(backend))
     return first[:b], mags[:b], signs[:b], widths[:b]
 
 
 def szp_dequant(first, mags, signs, eb: float,
-                backend: str = DEFAULT_BACKEND, tb: int = _sqk.DEFAULT_TB):
+                backend: Optional[str] = None, tb: int = _sqk.DEFAULT_TB):
     """Inverse of szp_quant -> (B, K) float32 reconstruction.
 
     The kernel's MXU tri-matmul cumulative sum is exact only while every
     partial delta sum stays below 2^24 (f32 integer exactness); callers
     must guard on the measured widths and fall back to backend="jnp"
-    (int32 cumsum) past that — see core.szp._dequant_backend_for.
+    (int32 cumsum) past that — see core.szp._dequant_guarded.
     """
     backend = resolve_backend(backend)
     if backend == "jnp":
@@ -93,20 +104,20 @@ def szp_dequant(first, mags, signs, eb: float,
     tb = _row_tile(b, tb)
     fp = pad_to_multiple(first, tb, axis=0)
     mp = pad_to_multiple(mags, tb, axis=0)
-    sp = pad_to_multiple(signs, tb, axis=0)
+    sp = pad_to_multiple(signs.astype(jnp.int32), tb, axis=0)
     out = _sqk.szp_dequant_blocks(fp, mp, sp, eb, tb=tb,
                                   interpret=_interp(backend))
     return out[:b]
 
 
 def local_pack(mags: jnp.ndarray, widths: jnp.ndarray, max_width: int = 32,
-               backend: str = DEFAULT_BACKEND, tb: int = _bpk.DEFAULT_TB):
+               backend: Optional[str] = None, tb: int = _bpk.DEFAULT_TB):
     """Tiled BE phase 1: per-block local byte pack -> (B, ceil(K*mw/8))."""
     backend = resolve_backend(backend)
     if backend == "jnp":
         return _ref.local_pack_ref(mags, widths, max_width)
-    b = mags.shape[0]
-    tb = _row_tile(b, tb)
+    b, k = mags.shape
+    tb = _row_tile(b, _bpk.tile_rows(k, max_width, tb), align=32)
     mp = pad_to_multiple(mags, tb, axis=0)
     wp = pad_to_multiple(widths.astype(jnp.int32), tb, axis=0,
                          mode="constant")
@@ -116,7 +127,7 @@ def local_pack(mags: jnp.ndarray, widths: jnp.ndarray, max_width: int = 32,
 
 
 def compact_bytes(local: jnp.ndarray, widths: jnp.ndarray, k: int,
-                  backend: str = DEFAULT_BACKEND, tb: int = _bck.DEFAULT_TB):
+                  backend: Optional[str] = None, tb: int = _bck.DEFAULT_TB):
     """Tiled BE phase 2: per-block rows -> contiguous payload.
 
     Same ``(buf, offs, total)`` contract as
@@ -133,7 +144,7 @@ def compact_bytes(local: jnp.ndarray, widths: jnp.ndarray, k: int,
     nb = block_nbytes(widths.astype(jnp.int32), k)
     offs = exclusive_cumsum(nb)
     total = (offs[-1] + nb[-1] if b > 0 else jnp.int32(0)).astype(jnp.int32)
-    tb = _row_tile(b, tb)
+    tb = _row_tile(b, tb, align=32)
     lp = pad_to_multiple(local, tb, axis=0, mode="constant")
     nbp = pad_to_multiple(nb, tb, axis=0, mode="constant")
     offp = pad_to_multiple(offs, tb, axis=0, mode="constant")
@@ -142,7 +153,7 @@ def compact_bytes(local: jnp.ndarray, widths: jnp.ndarray, k: int,
     return buf[: b * local.shape[1]], offs, total
 
 
-def cp_detect(field: jnp.ndarray, backend: str = DEFAULT_BACKEND,
+def cp_detect(field: jnp.ndarray, backend: Optional[str] = None,
               ty: int = _cpk.DEFAULT_TY, tx: int = _cpk.DEFAULT_TX):
     """Critical point classification -> int32 labels."""
     backend = resolve_backend(backend)
@@ -152,7 +163,7 @@ def cp_detect(field: jnp.ndarray, backend: str = DEFAULT_BACKEND,
 
 
 def extrema_restore(recon, labels, cur_labels, ranks, eb: float,
-                    backend: str = DEFAULT_BACKEND,
+                    backend: Optional[str] = None,
                     ty: int = _exk.DEFAULT_TY, tx: int = _exk.DEFAULT_TX):
     """Fused lost-extrema restoration -> corrected field."""
     backend = resolve_backend(backend)
@@ -163,7 +174,7 @@ def extrema_restore(recon, labels, cur_labels, ranks, eb: float,
 
 
 def shepard_refine(field: jnp.ndarray, sigma: float = 0.75, radius: int = 2,
-                   backend: str = DEFAULT_BACKEND,
+                   backend: Optional[str] = None,
                    ty: int = _rbk.DEFAULT_TY, tx: int = _rbk.DEFAULT_TX):
     """Separable convex RBF estimate (global sigma/radius hot path)."""
     backend = resolve_backend(backend)

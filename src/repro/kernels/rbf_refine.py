@@ -10,7 +10,7 @@ is separable:  exp(-(dy^2+dx^2)/2s^2) = g(dy) g(dx),  so
 Two elementwise 7-tap passes (row then column), each a single Pallas kernel
 over shifted operands — no halo DMA needed.  ``sigma``/``radius`` are
 *traced* scalars: the 7 taps are computed as a tiny jnp vector and fed to
-the kernel as an operand (scalar loads), so one compiled call serves every
+the kernel as an SMEM operand (scalar loads), so one compiled call serves every
 parameter value and the batched decompressor can vmap per-field params.
 This is the TPU hot path; the per-point-adaptive variant stays on the
 pure-jnp path (core/rbf.py), see DESIGN.md "hardware adaptation".
@@ -22,6 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_TY, DEFAULT_TX = 128, 128
 MAX_RADIUS = 3
@@ -36,10 +37,12 @@ def _taps(sigma, radius) -> jnp.ndarray:
 
 
 def _pass_kernel(taps_ref, *refs):
+    # taps arrive as a (1, 7) SMEM row: under vmap the batched (N, 1, 7)
+    # operand keeps full-extent trailing block dims, as Mosaic requires
     out_ref = refs[-1]
     acc = None
     for k, ref in enumerate(refs[:-1]):
-        term = ref[...] * taps_ref[k]
+        term = ref[...] * taps_ref[0, k]
         acc = term if acc is None else acc + term
     out_ref[...] = acc
 
@@ -69,18 +72,18 @@ def _run_pass(field: jnp.ndarray, taps: jnp.ndarray, axis: int, ty: int,
     out = pl.pallas_call(
         _pass_kernel,
         grid=(gy, gx),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] + [spec] * len(shifts),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [spec] * len(shifts),
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(shifts[0].shape, jnp.float32),
         interpret=interpret,
-    )(taps, *shifts)
+    )(taps.reshape(1, -1), *shifts)
     return out[:ny, :nx]
 
 
 @functools.partial(jax.jit, static_argnames=("ty", "tx", "interpret"))
 def shepard_refine_global(field: jnp.ndarray, sigma=0.75, radius=2,
                           ty: int = DEFAULT_TY, tx: int = DEFAULT_TX,
-                          interpret: bool = True) -> jnp.ndarray:
+                          interpret: bool = False) -> jnp.ndarray:
     """Separable convex RBF estimate of every point (center excluded)."""
     f = field.astype(jnp.float32)
     g = _taps(sigma, radius)

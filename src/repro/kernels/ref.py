@@ -14,7 +14,7 @@ from repro.utils import bitwidth, ulp_step
 
 
 def szp_quant_blocks_ref(xb: jnp.ndarray, eb: float):
-    """Oracle for kernels.szp_quant.szp_quant_blocks."""
+    """Oracle for ``ops.szp_quant`` (QZ + kernels.szp_quant.szp_delta_blocks)."""
     q = quantize(xb, eb)
     first = q[:, 0]
     deltas = q[:, 1:] - q[:, :-1]

@@ -43,10 +43,7 @@ RESULTS_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
 
 
 def _cost_dict(cost) -> dict:
-    """Normalize compiled.cost_analysis() across JAX versions (older
-    releases return a one-element list of dicts, newer a flat dict)."""
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
+    """The numeric entries of ``compiled.cost_analysis()``."""
     return {k: float(v) for k, v in (cost or {}).items()
             if isinstance(v, (int, float))}
 
@@ -238,16 +235,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, mesh=None,
         multi_pod=multi_pod)
     cfg = cfg if cfg is not None else registry.get_config(arch)
     sc = SHAPES[shape_name]
-    # Legacy XLA runs the compressed-DP step fully manual; the models'
-    # 'model'-axis sharding constraints are illegal inside that manual
-    # context, so leave the active mesh unset there (same degradation as
-    # launch.train: model-axis compute replicated per DP shard).
-    from repro.dist.compat import HAS_PARTIAL_AUTO
-    if (sc.mode != "train" or not getattr(cfg, "grad_compress", False)
-            or HAS_PARTIAL_AUTO):
-        set_active_mesh(mesh)
-    else:
-        set_active_mesh(None)
+    set_active_mesh(mesh)
 
     with mesh:
         lowered = _lower_cell(cfg, shape_name, mesh)

@@ -16,14 +16,15 @@ import jax.numpy as jnp
 from repro import faults, obs
 from repro.ckpt import CheckpointManager
 from repro.data import token_batches
-from repro.dist.compat import HAS_PARTIAL_AUTO
 from repro.launch.mesh import make_test_mesh
 from repro.models import lm, registry, set_active_mesh
 from repro.optim import adamw, wsd
 from repro.train import init_state, make_train_step, train_loop
+from repro.utils import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minicpm_2b")
     ap.add_argument("--smoke", action="store_true",
@@ -103,13 +104,7 @@ def main():
     mesh = None
     if args.data_parallel * args.model_parallel > 1:
         mesh = make_test_mesh(args.data_parallel, args.model_parallel)
-        # Legacy XLA runs the compressed-DP step fully manual (see
-        # dist.compat.HAS_PARTIAL_AUTO); the models' 'model'-axis
-        # sharding constraints are illegal inside that manual context,
-        # so leave the active mesh unset there (model-axis compute is
-        # replicated per DP shard, which is the documented degradation).
-        if not args.grad_compress or HAS_PARTIAL_AUTO:
-            set_active_mesh(mesh)
+        set_active_mesh(mesh)
 
     params = lm.init_params(cfg, jax.random.PRNGKey(args.seed))
     print(f"[train] arch={cfg.name} params={lm.param_count(params):,}")
@@ -151,8 +146,7 @@ def main():
     def rebuild_step(new_mesh):
         # shard_map steps close over the mesh; rebuild against the one
         # the elastic recovery produced (and point the models at it)
-        if not args.grad_compress or HAS_PARTIAL_AUTO:
-            set_active_mesh(new_mesh)
+        set_active_mesh(new_mesh)
         return make_train_step(cfg, optimizer, mesh=new_mesh,
                                grad_compress=args.grad_compress,
                                rel_eb=args.rel_eb,

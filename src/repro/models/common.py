@@ -17,7 +17,10 @@ _STRATEGY = "tp"
 
 
 def set_active_mesh(mesh) -> None:
-    """Register the mesh used by ``shard`` constraints (None disables)."""
+    """Register the mesh used by ``shard`` constraints (None disables).
+
+    ``shard`` places activations with ``with_sharding_constraint``, which
+    accepts only ``Auto`` mesh axes; build meshes with ``launch.mesh``."""
     global _ACTIVE_MESH
     _ACTIVE_MESH = mesh
 

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.compat import shard_map
+from jax import shard_map
 from repro.models.common import dense, ninit, shard
 
 

@@ -11,11 +11,11 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import jax
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.collectives import (WIRE_FORMATS, compressed_psum_tree,
                                     topo_compressed_psum_tree)
-from repro.dist.compat import HAS_PARTIAL_AUTO, shard_map
 from repro.dist.sharding import batch_axes
 from repro.models import lm
 from repro.train.state import TrainState
@@ -75,11 +75,6 @@ def make_train_step(cfg, optimizer, mesh=None, grad_compress: bool = False,
 
     assert mesh is not None, "compressed-DP mode needs the mesh"
     dp_axes = batch_axes(mesh)
-    # Partial-auto ('model' stays GSPMD-parallel) needs the modern
-    # jax.shard_map; legacy XLA fatally asserts on it for real model
-    # graphs, so there the whole step runs manual and the model-axis
-    # replicas redundantly compute their DP shard (correct, DP-only).
-    manual_axes = set(dp_axes) if HAS_PARTIAL_AUTO else None
 
     def per_shard(params, err, batch):
         # local-shard loss/grads; 'model' axis stays auto-parallel
@@ -110,7 +105,7 @@ def make_train_step(cfg, optimizer, mesh=None, grad_compress: bool = False,
             mesh=mesh,
             in_specs=(P(), P(), batch_specs),
             out_specs=(P(), P(), P()),
-            axis_names=manual_axes,
+            axis_names=set(dp_axes),
             check_vma=False,
         )
         loss, grads, err = sharded(state.params, state.err, batch)

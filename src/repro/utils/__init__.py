@@ -1,8 +1,30 @@
 """Small shared helpers used across the repro framework."""
 from __future__ import annotations
 
+import os
+
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+# <repo>/.jax_cache: a fixed path, because the cache directory is part of
+# every entry's key; a directory that moves between runs never hits.
+_REPO_CACHE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "..", "..", "..", ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it is (JAX reads
+    it itself) and nothing else is set.  Otherwise the cache lives in the
+    fixed in-repo ``.jax_cache`` directory (gitignored)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.normpath(_REPO_CACHE)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def cdiv(a: int, b: int) -> int:

@@ -1,6 +1,7 @@
 """CD-stage tests: classification semantics + edge handling."""
 import jax.numpy as jnp
 import numpy as np
+import pytest
 try:
     from hypothesis import given, settings, strategies as st
 except ImportError:          # no network in CI: deterministic shim
@@ -71,3 +72,48 @@ def test_property_kernel_matches_core(seed):
     ny, nx = rng.integers(3, 40), rng.integers(3, 40)
     f = jnp.asarray(rng.standard_normal((ny, nx)).astype(np.float32))
     assert bool(jnp.all(ops.cp_detect(f, backend="interpret") == classify(f)))
+
+
+def _ranks_reference(f, lab, q):
+    """RP ranks by a plain lexsort over (bin, type, value with minima
+    negated, index): the rank of a critical point is its 1-based position
+    among the critical points of its (bin, type) group."""
+    f, lab, q = (np.asarray(a).reshape(-1) for a in (f, lab, q))
+    sec = np.where(lab == MINIMA, -f, f)
+    order = np.lexsort((np.arange(f.size), sec, lab, q))
+    ranks = np.zeros(f.size, np.int32)
+    prev, r = None, 0
+    for i in order:
+        if lab[i] == REGULAR:
+            continue
+        r = r + 1 if (q[i], lab[i]) == prev else 1
+        prev = (q[i], lab[i])
+        ranks[i] = r
+    return ranks
+
+
+@pytest.mark.parametrize("kind", ["random", "ties", "signed_zeros",
+                                  "random_labels"])
+def test_compute_ranks_matches_lexsort_reference(kind):
+    import jax
+    from repro.core.quantize import quantize
+    from repro.core.relative_order import compute_ranks
+    ranks_of = jax.jit(lambda f, lab, eb: compute_ranks(f, lab,
+                                                        quantize(f, eb)))
+    rng = np.random.default_rng(["random", "ties", "signed_zeros",
+                                 "random_labels"].index(kind))
+    for shape in ((3, 5), (17, 23), (40, 33)) * 2:
+        f = rng.standard_normal(shape).astype(np.float32)
+        if kind == "ties":
+            f = np.round(f * 4) / 4
+        elif kind == "signed_zeros":
+            f = np.round(f * 2) / 2
+            f[rng.random(f.shape) < 0.3] = -0.0
+            f[rng.random(f.shape) < 0.2] = 0.0
+        fj = jnp.asarray(f)
+        lab = (jnp.asarray(rng.integers(0, 4, f.shape).astype(np.int32))
+               if kind == "random_labels" else classify(fj))
+        for eb in (1e-1, 1e-2, 1e-4):
+            got = np.asarray(ranks_of(fj, lab, eb)).reshape(-1)
+            np.testing.assert_array_equal(
+                got, _ranks_reference(f, lab, quantize(fj, eb)))

@@ -132,7 +132,7 @@ def test_unknown_wire_format_raises():
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.dist.collectives import compressed_psum_tree
-    from repro.dist.compat import shard_map
+    from jax import shard_map
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     with pytest.raises(ValueError, match="wire_format"):
         jax.jit(shard_map(
@@ -262,7 +262,7 @@ def test_psum_tree_empty_leaf():
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.dist.collectives import compressed_psum_tree
-    from repro.dist.compat import shard_map
+    from jax import shard_map
 
     tree = {"g": jnp.zeros((0,), jnp.float32),
             "h": jnp.ones((8,), jnp.float32)}
@@ -288,7 +288,7 @@ def test_topo_psum_tree_single_device():
     their exact fp32 inputs and the error feedback is zeroed there."""
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.dist.compat import shard_map
+    from jax import shard_map
 
     rng = np.random.default_rng(0)
     g = (rng.standard_normal(4096) * 1e-3).astype(np.float32)
@@ -376,7 +376,7 @@ def test_topo_psum_exact_multi_device():
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.dist.collectives import protect_k, topo_compressed_psum_tree
-        from repro.dist.compat import shard_map
+        from jax import shard_map
 
         n, size, topo_frac = 8, 4096, 1e-2
         rng = np.random.default_rng(0)

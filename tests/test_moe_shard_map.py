@@ -13,8 +13,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def test_shard_map_matches_einsum_multi_device():
     py = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import AxisType
         from repro.models import lm, registry, set_active_mesh
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                             axis_types=(AxisType.Auto,) * 2)
         cfg_e = registry.get_smoke_config('olmoe_1b_7b').replace(
             capacity_factor=8.0)
         cfg_s = cfg_e.replace(moe_impl='shard_map')

@@ -18,7 +18,7 @@ from repro.ckpt import AsyncWriteError, AsyncWriter, CheckpointManager
 from repro.core.szp import szp_compress, szp_decompress
 from repro.core.toposzp import toposzp_compress, toposzp_decompress
 from repro.dist.collectives import compressed_psum_tree
-from repro.dist.compat import shard_map
+from jax import shard_map
 from repro.dist.ring import packed_wire_summary
 from repro.models import lm, registry
 from repro.obs.registry import Registry, _env_enabled

@@ -15,7 +15,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.quantize import quantize
 from repro.dist.collectives import (_leaf_eb, compressed_psum_tree,
                                     topo_compressed_psum_tree)
-from repro.dist.compat import shard_map
+from jax import shard_map
 from repro.dist.ring import (base_width, packed_wire_summary, ring_perm,
                              simulate_hop_bytes)
 
@@ -144,7 +144,7 @@ def test_packed_ring_bit_identical_multi_device():
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.dist.collectives import protect_k, topo_compressed_psum_tree
-        from repro.dist.compat import shard_map
+        from jax import shard_map
 
         n, size, topo_frac = 8, 5000, 1e-2
         rng = np.random.default_rng(0)
@@ -204,7 +204,7 @@ def test_psum_leaf_widens_at_tiny_rel_eb_multi_device():
         import jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
         from repro.dist.collectives import compressed_psum_tree
-        from repro.dist.compat import shard_map
+        from jax import shard_map
 
         n = 8
         mesh = Mesh(np.array(jax.devices()[:n]), ('data',))
