@@ -175,21 +175,12 @@ def _pack_stage(first, mags, signs, widths, max_width: int,
                                backend=backend)
 
 
-def _obs_stream(parts: SZpParts, pipeline: str, mode: str) -> None:
-    """Static stream accounting: calls + the capacity-formula bytes.
-
-    Every number here comes from array SHAPES (aval metadata, host-known
-    without any device read), so recording it keeps the zero-sync
-    guarantee on both the classic and the resident path."""
-    if not obs.enabled():
-        return
-    batched = parts.widths.ndim == 2
-    calls = parts.widths.shape[0] if batched else 1
-    cap = (HEADER_BYTES * calls + parts.const_bits.size + parts.widths.size
-           + parts.signs.size + 4 * parts.first.size + parts.payload.size)
-    obs.counter_add(f"{pipeline}.compress.calls", calls)
-    obs.counter_add(f"{pipeline}.compress.{mode}_calls", calls)
-    obs.counter_add(f"{pipeline}.compress.cap_bytes", float(cap))
+def _obs_stream(parts: SZpParts, pipeline: str) -> None:
+    """``<pipeline>.compress.calls``: fields compressed.  Read from array
+    SHAPES (host-known without any device read), so recording it keeps the
+    zero-sync guarantee on both the classic and the resident path."""
+    obs.counter_add(f"{pipeline}.compress.calls",
+                    parts.widths.shape[0] if parts.widths.ndim == 2 else 1)
 
 
 def _bucket_index(w_max: jnp.ndarray) -> jnp.ndarray:
@@ -296,7 +287,7 @@ def szp_compress(x: jnp.ndarray, eb, block: int = DEFAULT_BLOCK,
                 parts = _compress_resident(
                     _quant_stage_donated if donate else _quant_stage,
                     x, eb, block, backend, batched=False)
-        _obs_stream(parts, "szp", "resident")
+        _obs_stream(parts, "szp")
         return parts
     with obs.span("compress.quant", pipeline="szp", backend=backend):
         first, mags, signs, widths, w_max = _quant_stage(
@@ -304,7 +295,7 @@ def szp_compress(x: jnp.ndarray, eb, block: int = DEFAULT_BLOCK,
         mw = bitpack.width_bucket(int(w_max))   # the existing sync point
     with obs.span("compress.pack", pipeline="szp", width_bucket=mw):
         parts = _pack_stage(first, mags, signs, widths, mw, backend)
-    _obs_stream(parts, "szp", "classic")
+    _obs_stream(parts, "szp")
     obs.counter_add(f"szp.compress.bucket_{mw}", 1)
     return parts
 
@@ -365,7 +356,7 @@ def szp_decompress(parts: SZpParts, shape: Sequence[int], eb,
     n = 1
     for s in shape:
         n *= s
-    with obs.span("decompress.restore", pipeline="szp", backend=backend):
+    with obs.span("decompress", pipeline="szp", backend=backend):
         out = _dequant_guarded(parts, n, eb, block, recon, backend)
     obs.counter_add("szp.decompress.calls", 1)
     return out.reshape(shape)
@@ -387,8 +378,10 @@ def _dequant_backend_for(parts: SZpParts, block: int, backend: str) -> str:
 @functools.partial(jax.jit, static_argnames=("max_width", "backend"))
 def _pack_stage_batch(first, mags, signs, widths, max_width: int,
                       backend: str) -> SZpParts:
-    return jax.vmap(lambda f, m, s, w: _assemble_parts(
-        f, m, s, w, max_width, backend=backend))(first, mags, signs, widths)
+    with jax.named_scope("szp.stage_pack"):
+        return jax.vmap(lambda f, m, s, w: _assemble_parts(
+            f, m, s, w, max_width, backend=backend))(first, mags, signs,
+                                                      widths)
 
 
 def szp_compress_batch(xs: jnp.ndarray, eb,
@@ -414,7 +407,7 @@ def szp_compress_batch(xs: jnp.ndarray, eb,
                     _quant_stage_batch_donated if donate
                     else _quant_stage_batch,
                     xs, eb, block, backend, batched=True)
-        _obs_stream(parts, "szp", "resident")
+        _obs_stream(parts, "szp")
         return parts
     with obs.span("compress.quant", pipeline="szp", backend=backend,
                   batch=xs.shape[0]):
@@ -424,7 +417,7 @@ def szp_compress_batch(xs: jnp.ndarray, eb,
     with obs.span("compress.pack", pipeline="szp", width_bucket=mw):
         parts = _pack_stage_batch(first, mags, signs, widths, max_width=mw,
                                   backend=backend)
-    _obs_stream(parts, "szp", "classic")
+    _obs_stream(parts, "szp")
     obs.counter_add(f"szp.compress.bucket_{mw}", xs.shape[0])
     return parts
 
@@ -464,7 +457,7 @@ def szp_decompress_batch(parts: SZpParts, shape: Sequence[int], eb,
     n = 1
     for s in shape:
         n *= s
-    with obs.span("decompress.restore", pipeline="szp", backend=backend,
+    with obs.span("decompress", pipeline="szp", backend=backend,
                   batch=parts.widths.shape[0]):
         out = _dequant_guarded_batch(parts, n=n, eb=eb, block=block,
                                      recon=recon, backend=backend)

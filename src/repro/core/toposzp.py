@@ -40,9 +40,9 @@ from repro.core.relative_order import compute_ranks
 from repro.core.stencils import apply_extrema_stencils
 from repro.core.szp import (DEFAULT_BLOCK, HEADER_BYTES, SZpParts,
                             _assemble_parts, _blocked_codes, _blocked_field,
-                            _delta_blocks, _pack_switch, _quiet_donation,
-                            _unpack_sections, decompress_codes,
-                            tri_guard_width)
+                            _delta_blocks, _obs_stream, _pack_switch,
+                            _quiet_donation, _unpack_sections,
+                            decompress_codes, tri_guard_width)
 from repro.kernels import ops
 
 
@@ -100,7 +100,8 @@ def _compress_measure(field: jnp.ndarray, eb: float, block: int,
     # --- CD + RP (the lightweight topology stage, before lossy QZ) ---
     with jax.named_scope("toposzp.stage_detect"):
         labels = ops.cp_detect(field, backend=backend)
-        ranks = compute_ranks(field, labels, codes)
+        with jax.named_scope("toposzp.stage_rp"):
+            ranks = compute_ranks(field, labels, codes)
 
     # --- QZ + LZ fused over (B, K) blocks ---
     with jax.named_scope("toposzp.stage_quant"):
@@ -190,28 +191,6 @@ def _compress_resident(measure, fields, eb, block: int, backend: str,
                           backend=backend, batched=batched)
 
 
-def _obs_topo_stream(comp: TopoSZpCompressed, mode: str) -> None:
-    """Static stream accounting: calls + the capacity-formula bytes over
-    both bitpacked streams and the label map.  Every number comes from
-    array SHAPES (aval metadata, host-known without any device read), so
-    recording it keeps the zero-sync guarantee on both the classic and
-    the resident path."""
-    if not obs.enabled():
-        return
-    batched = comp.szp.widths.ndim == 2
-    calls = comp.szp.widths.shape[0] if batched else 1
-
-    def cap(parts: SZpParts) -> int:
-        return (HEADER_BYTES * calls + parts.const_bits.size
-                + parts.widths.size + parts.signs.size
-                + 4 * parts.first.size + parts.payload.size)
-
-    total = cap(comp.szp) + cap(comp.ranks) + comp.labels2b.size
-    obs.counter_add("toposzp.compress.calls", calls)
-    obs.counter_add(f"toposzp.compress.{mode}_calls", calls)
-    obs.counter_add("toposzp.compress.cap_bytes", float(total))
-
-
 def toposzp_compress(field: jnp.ndarray, eb,
                      block: int = DEFAULT_BLOCK,
                      backend: Optional[str] = None, resident: bool = False,
@@ -230,7 +209,7 @@ def toposzp_compress(field: jnp.ndarray, eb,
                 comp = _compress_resident(
                     _measure_one_donated if donate else _measure_one,
                     field, eb, block, backend, batched=False)
-        _obs_topo_stream(comp, "resident")
+        _obs_stream(comp.szp, "toposzp")
         return comp
     with obs.span("compress.quant", pipeline="toposzp", backend=backend,
                   includes="detect+quant"):
@@ -245,7 +224,7 @@ def toposzp_compress(field: jnp.ndarray, eb,
         comp = _pack_streams(main, rank, labels2b, n_cp, block=block,
                              mw_main=mw_main, mw_rank=mw_rank,
                              backend=backend)
-    _obs_topo_stream(comp, "classic")
+    _obs_stream(comp.szp, "toposzp")
     obs.counter_add(f"toposzp.compress.bucket_{mw_main}", 1)
     return comp
 
@@ -276,7 +255,7 @@ def toposzp_compress_batch(fields: jnp.ndarray, eb,
                 comp = _compress_resident(
                     _measure_batch_donated if donate else _measure_batch,
                     fields, eb, block, backend, batched=True)
-        _obs_topo_stream(comp, "resident")
+        _obs_stream(comp.szp, "toposzp")
         return comp
     with obs.span("compress.quant", pipeline="toposzp", backend=backend,
                   includes="detect+quant", batch=fields.shape[0]):
@@ -290,7 +269,7 @@ def toposzp_compress_batch(fields: jnp.ndarray, eb,
         comp = _pack_streams(main, rank, labels2b, n_cp, block=block,
                              mw_main=mw_main, mw_rank=mw_rank,
                              backend=backend, batched=True)
-    _obs_topo_stream(comp, "classic")
+    _obs_stream(comp.szp, "toposzp")
     obs.counter_add(f"toposzp.compress.bucket_{mw_main}", fields.shape[0])
     return comp
 
@@ -336,29 +315,33 @@ def _decode_field(comp: TopoSZpCompressed, shape, eb: float, block: int,
     ny, nx = shape
     n = ny * nx
 
-    # --- QZ^ through the kernel dequant (guarded by the caller) ---
-    mags, signs, _ = _unpack_sections(comp.szp, block)
-    base = ops.szp_dequant(comp.szp.first, mags, signs[:, 1:], eb,
-                           backend=deq_backend)
-    if recon == "left":
-        base = base - eb
-    elif recon != "center":
-        raise ValueError(f"unknown recon mode: {recon}")
-    base = base.reshape(-1)[:n].reshape(shape)
+    with jax.named_scope("toposzp.stage_decode"):
+        # --- QZ^ through the kernel dequant (guarded by the caller) ---
+        mags, signs, _ = _unpack_sections(comp.szp, block)
+        base = ops.szp_dequant(comp.szp.first, mags, signs[:, 1:], eb,
+                               backend=deq_backend)
+        if recon == "left":
+            base = base - eb
+        elif recon != "center":
+            raise ValueError(f"unknown recon mode: {recon}")
+        base = base.reshape(-1)[:n].reshape(shape)
 
-    # --- MD^: metadata extraction ---
-    labels = bitpack.unpack_2bit(comp.labels2b, n).reshape(shape)
-    labels_flat = labels.reshape(-1)
-    # sparse rank stream: CP-first order; the stream may be trimmed to its
-    # used prefix (deserialization), so decode its actual block count.
-    # Rank codes must stay lossless -> always the exact int32 path.
-    n_codes = comp.ranks.widths.shape[0] * block
-    ranks_sorted = decompress_codes(comp.ranks, min(n_codes, n), block=block)
-    if n_codes < n:
-        ranks_sorted = jnp.concatenate(
-            [ranks_sorted, jnp.zeros(n - n_codes, jnp.int32)])
-    dest = _cp_first_dest(labels_flat)
-    ranks = ranks_sorted[:n][dest].reshape(shape)
+        # --- MD^: metadata extraction ---
+        with jax.named_scope("toposzp.stage_decode_md"):
+            labels = bitpack.unpack_2bit(comp.labels2b, n).reshape(shape)
+            labels_flat = labels.reshape(-1)
+            # sparse rank stream: CP-first order; the stream may be trimmed
+            # to its used prefix (deserialization), so decode its actual
+            # block count.  Rank codes must stay lossless -> always the
+            # exact int32 path.
+            n_codes = comp.ranks.widths.shape[0] * block
+            ranks_sorted = decompress_codes(comp.ranks, min(n_codes, n),
+                                            block=block)
+            if n_codes < n:
+                ranks_sorted = jnp.concatenate(
+                    [ranks_sorted, jnp.zeros(n - n_codes, jnp.int32)])
+            dest = _cp_first_dest(labels_flat)
+            ranks = ranks_sorted[:n][dest].reshape(shape)
     return base, labels, ranks
 
 
@@ -430,7 +413,7 @@ def toposzp_decompress(comp: TopoSZpCompressed, shape: Sequence[int],
       * zero FP, zero FT w.r.t. the original label map
     """
     backend = ops.resolve_backend(backend)
-    with obs.span("decompress.restore", pipeline="toposzp", backend=backend):
+    with obs.span("decompress", pipeline="toposzp", backend=backend):
         out = _decompress_one(comp, eb, shape=tuple(shape), block=block,
                               rbf_mode=rbf_mode, recon=recon,
                               backend=backend)
@@ -448,7 +431,7 @@ def toposzp_decompress_batch(comp: TopoSZpCompressed, shape: Sequence[int],
     dequant guard, no host syncs)."""
     backend = ops.resolve_backend(backend)
     nb = comp.szp.widths.shape[0]
-    with obs.span("decompress.restore", pipeline="toposzp", backend=backend,
+    with obs.span("decompress", pipeline="toposzp", backend=backend,
                   batch=nb):
         out = _decompress_batch(comp, eb, shape=tuple(shape), block=block,
                                 rbf_mode=rbf_mode, recon=recon,
