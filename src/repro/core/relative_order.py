@@ -17,7 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.critical_points import MINIMA, REGULAR
+from repro.core.critical_points import MAXIMA, MINIMA, SADDLE
 
 
 def _sort_key(x: jnp.ndarray) -> jnp.ndarray:
@@ -29,9 +29,9 @@ def _sort_key(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(i < 0, i ^ jnp.int32(0x7FFFFFFF), i)
 
 
-def _stable_order(key: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
-    """``perm`` stably reordered by ``key[perm]`` (one int32 key sort)."""
-    return jax.lax.sort((key[perm], perm), num_keys=1, is_stable=True)[1]
+# A point's label rides above its flat index in one int32 sort payload.
+_TAG_SHIFT = 29
+_POS_MASK = (1 << _TAG_SHIFT) - 1
 
 
 def compute_ranks(field: jnp.ndarray, labels: jnp.ndarray,
@@ -54,26 +54,44 @@ def compute_ranks(field: jnp.ndarray, labels: jnp.ndarray,
     the other types interleave, so a point's rank is the running count of
     its own type since the bin's first point: the same ranks as a
     (bin, type, value) lexsort.
-    """
-    f = field.astype(jnp.float32).reshape(-1)
-    lab = labels.reshape(-1)
-    q = codes.reshape(-1)
-    n = f.shape[0]
 
-    # secondary key: value ascending, except minima descending.
-    sec = jnp.where(lab == MINIMA, -f, f)
-    pos = jnp.arange(n, dtype=jnp.int32)
-    order = _stable_order(q, _stable_order(_sort_key(sec), pos))
-    q_s, lab_s = q[order], lab[order]
-    new_seg = jnp.concatenate([jnp.array([True]), q_s[1:] != q_s[:-1]])
-    seg_start = jax.lax.cummax(jnp.where(new_seg, pos, 0))
-    # inclusive running count of each point's own type, restarted per bin
-    onehot = (lab_s[:, None] == jnp.arange(1, 4, dtype=jnp.int32)[None, :])
-    counts = jnp.cumsum(onehot.astype(jnp.int32), axis=0)         # (n, 3)
-    before = jnp.where((seg_start > 0)[:, None],
-                       counts[jnp.maximum(seg_start - 1, 0)], 0)
-    own = jnp.clip(lab_s - 1, 0, 2)[:, None]
-    rank_sorted = jnp.take_along_axis(counts - before, own, axis=1)[:, 0]
-    ranks = jnp.zeros(n, jnp.int32).at[order].set(
-        jnp.where(lab_s != REGULAR, rank_sorted, 0), unique_indices=True)
+    There is no gather and no scatter: every permutation is applied by
+    carrying the data through ``lax.sort`` as payload operands.  On a TPU
+    v5e, at CESM-ATM 1800x3600 (6.48M points, 16 fields per batch), one
+    sort took ~24 ms per field while applying its permutation by a gather
+    took 96-150 ms; the gather form applied permutations five times and
+    scattered the ranks back, ~670 ms of RP per field (PERF.md).
+
+    The label rides in the index payload as ``index | label << 29``, so a
+    field must hold fewer than 2**29 points (checked from the shape).  The
+    running count restarts per bin without an indexed lookup: a type's
+    inclusive count never decreases along the sorted order, so a running
+    ``cummax`` of its exclusive count taken at bin starts (0 elsewhere) is
+    the count before the current bin.  A last sort by index returns the
+    ranks to the field's order.
+    """
+    n = field.size
+    if n > _POS_MASK:
+        raise ValueError(f"compute_ranks takes fewer than 2**{_TAG_SHIFT} "
+                         f"points, got {n}")
+    f = field.astype(jnp.float32)
+    # secondary key: value ascending, except minima descending.  Key and
+    # tag are made at the field's shape and flattened after: a sort whose
+    # operand fused the minima flip with the flattening took the TPU
+    # compiler ~5x as long.
+    key = _sort_key(jnp.where(labels == MINIMA, -f, f)).reshape(-1)
+    tag = (jnp.arange(n, dtype=jnp.int32).reshape(field.shape)
+           | (labels << _TAG_SHIFT)).reshape(-1)
+    q = codes.reshape(-1)
+    _, q, tag = jax.lax.sort((key, q, tag), num_keys=1, is_stable=True)
+    q, tag = jax.lax.sort((q, tag), num_keys=1, is_stable=True)
+    lab = tag >> _TAG_SHIFT
+    new_bin = jnp.concatenate([jnp.array([True]), q[1:] != q[:-1]])
+    rank = jnp.zeros(n, jnp.int32)
+    for t in (MINIMA, SADDLE, MAXIMA):
+        own = (lab == t).astype(jnp.int32)
+        count = jnp.cumsum(own)     # inclusive running count of type t
+        before = jax.lax.cummax(jnp.where(new_bin, count - own, 0))
+        rank = jnp.where(own == 1, count - before, rank)
+    ranks = jax.lax.sort((tag & _POS_MASK, rank), num_keys=1)[1]
     return ranks.reshape(field.shape)

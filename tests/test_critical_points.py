@@ -92,28 +92,88 @@ def _ranks_reference(f, lab, q):
     return ranks
 
 
-@pytest.mark.parametrize("kind", ["random", "ties", "signed_zeros",
-                                  "random_labels"])
+RANK_KINDS = ["random", "ties", "signed_zeros", "random_labels",
+              "vmapped_ties", "all_critical", "all_regular"]
+
+
+def _rank_case(rng, kind, shape):
+    """(field, labels) of one test field of ``kind``."""
+    f = rng.standard_normal(shape).astype(np.float32)
+    if kind in ("ties", "vmapped_ties"):
+        f = np.round(f * 4) / 4
+    elif kind == "signed_zeros":
+        f = np.round(f * 2) / 2
+        f[rng.random(f.shape) < 0.3] = -0.0
+        f[rng.random(f.shape) < 0.2] = 0.0
+    elif kind == "all_critical":
+        # distinct values 0.5 apart: one point per bin at eb <= 0.1
+        f = (rng.permutation(f.size).reshape(shape) / 2).astype(np.float32)
+    if kind == "random_labels":
+        lab = rng.integers(0, 4, shape)
+    elif kind == "all_critical":
+        lab = rng.integers(1, 4, shape)
+    elif kind == "all_regular":
+        lab = np.zeros(shape)
+    else:
+        lab = classify(jnp.asarray(f))
+    return f, jnp.asarray(lab, jnp.int32)
+
+
+@pytest.mark.parametrize("kind", RANK_KINDS)
 def test_compute_ranks_matches_lexsort_reference(kind):
     import jax
     from repro.core.quantize import quantize
     from repro.core.relative_order import compute_ranks
-    ranks_of = jax.jit(lambda f, lab, eb: compute_ranks(f, lab,
-                                                        quantize(f, eb)))
-    rng = np.random.default_rng(["random", "ties", "signed_zeros",
-                                 "random_labels"].index(kind))
+    batch = 2 if kind == "vmapped_ties" else 1
+
+    def ranks(f, lab, eb):
+        return compute_ranks(f, lab, quantize(f, eb))
+    ranks_of = jax.jit(jax.vmap(ranks, in_axes=(0, 0, None)) if batch > 1
+                       else ranks)
+    # all_critical: one point per bin, then every point in a single bin
+    ebs = (1e-1, 1e4) if kind == "all_critical" else (1e-1, 1e-2, 1e-4)
+    rng = np.random.default_rng(RANK_KINDS.index(kind))
     for shape in ((3, 5), (17, 23), (40, 33)) * 2:
-        f = rng.standard_normal(shape).astype(np.float32)
-        if kind == "ties":
-            f = np.round(f * 4) / 4
-        elif kind == "signed_zeros":
-            f = np.round(f * 2) / 2
-            f[rng.random(f.shape) < 0.3] = -0.0
-            f[rng.random(f.shape) < 0.2] = 0.0
-        fj = jnp.asarray(f)
-        lab = (jnp.asarray(rng.integers(0, 4, f.shape).astype(np.int32))
-               if kind == "random_labels" else classify(fj))
-        for eb in (1e-1, 1e-2, 1e-4):
-            got = np.asarray(ranks_of(fj, lab, eb)).reshape(-1)
-            np.testing.assert_array_equal(
-                got, _ranks_reference(f, lab, quantize(fj, eb)))
+        cases = [_rank_case(rng, kind, shape) for _ in range(batch)]
+        fj = jnp.asarray(np.stack([f for f, _ in cases]))
+        lab = jnp.stack([lab for _, lab in cases])
+        if batch == 1:
+            fj, lab = fj[0], lab[0]
+        for eb in ebs:
+            got = np.asarray(ranks_of(fj, lab, eb)).reshape(batch, -1)
+            for b, (f, lab_b) in enumerate(cases):
+                np.testing.assert_array_equal(
+                    got[b], _ranks_reference(f, lab_b,
+                                             quantize(jnp.asarray(f), eb)))
+            if kind == "all_regular":
+                assert not got.any()
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_compute_ranks_lowers_without_gather_or_scatter(batched):
+    """RP applies its permutations as sort payloads: the lowered program,
+    alone or vmapped over a batch as pass 1 runs it, holds sorts and no
+    gather or scatter (each cost several sorts' time on the TPU)."""
+    import re
+
+    import jax
+    from repro.core.relative_order import compute_ranks
+    shape = (2, 17, 23) if batched else (17, 23)
+    f = jnp.zeros(shape, jnp.float32)
+    i = jnp.zeros(shape, jnp.int32)
+    fn = jax.vmap(compute_ranks) if batched else compute_ranks
+    ops = set(re.findall(r"stablehlo\.\w+",
+                         jax.jit(fn).lower(f, i, i).as_text()))
+    assert "stablehlo.sort" in ops
+    assert not ops & {"stablehlo.gather", "stablehlo.scatter"}
+
+
+def test_compute_ranks_refuses_2_pow_29_points():
+    """The label rides above 29 index bits of the sort payload: a field of
+    2**29 points is refused from its shape alone (nothing allocated)."""
+    import jax
+    from repro.core.relative_order import compute_ranks
+    f = jax.ShapeDtypeStruct((1, 2**29), jnp.float32)
+    i = jax.ShapeDtypeStruct((1, 2**29), jnp.int32)
+    with pytest.raises(ValueError, match=r"2\*\*29"):
+        jax.eval_shape(compute_ranks, f, i, i)
