@@ -28,6 +28,8 @@ import os
 import re
 from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
+from bench import spec
+
 CALL_SPAN = "bench.call"
 
 
@@ -352,7 +354,7 @@ class Context:
         cfg = cell.config
         self.operation = cell.traffic["operation"]
         self.compressor = cfg["compressor"]
-        self.n_points = int(cfg["grid"][0]) * int(cfg["grid"][1])
+        self.n_points = spec.grid_points(cfg)
         self.fields = red.calls * int(cfg["fields_per_call"])
 
     def idle_pct(self) -> float:

@@ -1,17 +1,23 @@
-"""CESM-like 2-D fields made on the device from a seed.
+"""CESM-like fields made on the device from a seed, on the 2-D or 3-D
+grid of the call.
 
 A jax.random port of the three generators of ``src/repro/data/fields.py``
 (its parameters and, save the ``periodic_x`` option below, its formulas;
 device RNG instead of numpy's), so a run's fields cost one small jitted
-program per generator and no host work:
+program per generator and no host work.  Each generator takes the grid's
+rank from its shape: the spectrum runs over every axis, a bump has one
+Gaussian factor per axis.  On a 2-D grid they trace the same programs as
+the 2-D-only generators the 2-D cells were first measured with, so a
+seed's 2-D fields keep every bit (``bench/tests/test_bench_fields.py``
+holds checksums of them).
 
 * ``grf``: band-limited Gaussian random field (power-law spectrum).  Smooth
   at large scales with critical points spread evenly over the grid: the
   bulk of CESM-ATM's fields.
 * ``vortex``: a superposition of Gaussian bumps and dips.  Large smooth,
   flat areas with few, isolated extrema and saddles: the compressible end.
-  With ``periodic_x`` the bumps wrap around in x, as fields on a
-  latitude-longitude grid wrap in longitude.
+  With ``periodic_x`` the bumps wrap around in x (the last axis), as
+  fields on a latitude-longitude grid wrap in longitude.
 * ``multiscale``: GRF + vortices + white noise.  Critical points on a large
   share of the grid: the hard case for CD, RP and the restore loop.
 
@@ -51,69 +57,84 @@ def _normalize(f: jnp.ndarray) -> jnp.ndarray:
     return ((f - lo) / jnp.maximum(hi - lo, 1e-30)).astype(jnp.float32)
 
 
-def _grf(key, ny: int, nx: int, power: float) -> jnp.ndarray:
-    white = jax.random.normal(key, (ny, nx), jnp.float32)
-    fy = jnp.fft.fftfreq(ny)[:, None]
-    fx = jnp.fft.rfftfreq(nx)[None, :]
-    k = jnp.sqrt(fy * fy + fx * fx)
+def _along(v: jnp.ndarray, ndim: int, axis: int) -> jnp.ndarray:
+    """Vector ``v`` laid along ``axis`` of an ``ndim``-D grid."""
+    return v[tuple(slice(None) if a == axis else None for a in range(ndim))]
+
+
+def _grf(key, shape: tuple, power: float) -> jnp.ndarray:
+    white = jax.random.normal(key, shape, jnp.float32)
+    last = len(shape) - 1
+    freqs = [_along(jnp.fft.rfftfreq(n) if ax == last
+                    else jnp.fft.fftfreq(n), len(shape), ax)
+             for ax, n in enumerate(shape)]
+    k2 = freqs[0] * freqs[0]
+    for freq in freqs[1:]:
+        k2 = k2 + freq * freq
+    k = jnp.sqrt(k2)
     amp = jnp.where(k == 0, 0.0, jnp.maximum(k, 1e-6) ** (-power / 2.0))
-    f = jnp.fft.irfft2(jnp.fft.rfft2(white) * amp, s=(ny, nx))
+    f = jnp.fft.irfftn(jnp.fft.rfftn(white) * amp, s=shape)
     return _normalize(f)
 
 
-def _vortex(key, ny: int, nx: int, n_vortices: int,
+def _vortex(key, shape: tuple, n_vortices: int,
             periodic_x: bool = False) -> jnp.ndarray:
     """Sum of ``a * exp(-r^2 / 2s^2)`` bumps, computed as the separable
-    product it is: (ny, V) x diag(a) x (V, nx), at full f32 precision.
-    ``periodic_x``: x runs over [0, 1) and distances in x wrap around."""
+    product it is: one Gaussian factor (n_axis, V) per axis, the leading
+    ones multiplied out with diag(a) to (points, V), contracted with the
+    last axis's (x) factor at full f32 precision.  ``periodic_x``: x runs
+    over [0, 1) and distances in x wrap around."""
     kc, ks, ka = jax.random.split(key, 3)
-    c = jax.random.uniform(kc, (n_vortices, 2), jnp.float32)
+    c = jax.random.uniform(kc, (n_vortices, len(shape)), jnp.float32)
     s = jax.random.uniform(ks, (n_vortices,), jnp.float32, 0.02, 0.12)
     a = jax.random.uniform(ka, (n_vortices,), jnp.float32, -1.0, 1.0)
-    y = jnp.linspace(0.0, 1.0, ny, dtype=jnp.float32)
+    *lead_n, nx = shape
+    coords = [jnp.linspace(0.0, 1.0, n, dtype=jnp.float32) for n in lead_n]
     if periodic_x:
         x = jnp.arange(nx, dtype=jnp.float32) / nx
     else:
         x = jnp.linspace(0.0, 1.0, nx, dtype=jnp.float32)
-    dx = x[:, None] - c[None, :, 1]
+    # keep this order (x's distances, the factors, then diag(a)): on a 2-D
+    # grid it traces the program the 2-D cells' fields were made by
+    dx = x[:, None] - c[None, :, len(lead_n)]
     if periodic_x:
         dx = dx - jnp.round(dx)                      # nearest image
     inv = 1.0 / (2.0 * s * s)
-    gy = jnp.exp(-((y[:, None] - c[None, :, 0]) ** 2) * inv[None, :])
+    gs = [jnp.exp(-((y[:, None] - c[None, :, ax]) ** 2) * inv[None, :])
+          for ax, y in enumerate(coords)]
     gx = jnp.exp(-(dx ** 2) * inv[None, :])
-    f = jnp.matmul(gy * a[None, :], gx.T,
-                   precision=jax.lax.Precision.HIGHEST)
-    return _normalize(f)
+    lead = gs[0] * a[None, :]
+    for g in gs[1:]:
+        lead = (lead[:, None, :] * g[None, :, :]).reshape(-1, n_vortices)
+    f = jnp.matmul(lead, gx.T, precision=jax.lax.Precision.HIGHEST)
+    return _normalize(f.reshape(shape))
 
 
-def _multiscale(key, ny: int, nx: int, power: float, n_vortices: int,
+def _multiscale(key, shape: tuple, power: float, n_vortices: int,
                 grf_weight: float, vortex_weight: float, noise: float,
                 periodic_x: bool = False) -> jnp.ndarray:
     kg, kv, kn = jax.random.split(key, 3)
-    f = (grf_weight * _grf(kg, ny, nx, power)
-         + vortex_weight * _vortex(kv, ny, nx, n_vortices, periodic_x)
-         + noise * jax.random.normal(kn, (ny, nx), jnp.float32))
+    f = (grf_weight * _grf(kg, shape, power)
+         + vortex_weight * _vortex(kv, shape, n_vortices, periodic_x)
+         + noise * jax.random.normal(kn, shape, jnp.float32))
     return _normalize(f)
 
 
 _GENERATORS = {"grf": _grf, "vortex": _vortex, "multiscale": _multiscale}
 
 
-def _one(key, ny: int, nx: int, spec: dict) -> jnp.ndarray:
-    params = {k: v for k, v in spec.items() if k != "generator"}
-    return _GENERATORS[spec["generator"]](key, ny, nx, **params)
-
-
 @functools.partial(jax.jit, static_argnames=("shape", "spec"))
 def _field(key, i, shape: tuple, spec: str) -> jnp.ndarray:
     """Field ``i`` of a call, from its own key; one program per generator
     spec, whatever ``i`` and the number of fields."""
-    ny, nx = shape
-    return _one(jax.random.fold_in(key, i), ny, nx, json.loads(spec))
+    params = json.loads(spec)
+    gen = _GENERATORS[params.pop("generator")]
+    return gen(jax.random.fold_in(key, i), shape, **params)
 
 
 def make_fields(seed: int, n: int, shape, mix) -> jnp.ndarray:
-    """(n, ny, nx) float32 fields on the default device, from ``seed``.
+    """(n, *shape) float32 fields on the default device, from ``seed``;
+    ``shape`` is the configuration's grid, (ny, nx) or (nz, ny, nx).
 
     ``mix`` is the traffic file's list of generator specs, e.g.
     ``[{"generator": "grf", "power": 3.0}, ...]``."""
@@ -121,6 +142,8 @@ def make_fields(seed: int, n: int, shape, mix) -> jnp.ndarray:
         if spec.get("generator") not in _GENERATORS:
             raise ValueError(f"unknown field generator in {spec}")
     key, shape = seed_key(seed), tuple(int(s) for s in shape)
+    if len(shape) not in (2, 3):
+        raise ValueError(f"grid {shape} is neither 2-D nor 3-D")
     specs = [json.dumps(spec, sort_keys=True) for spec in mix]
     return jnp.stack([_field(key, jnp.int32(i), shape, specs[i % len(specs)])
                       for i in range(int(n))])

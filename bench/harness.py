@@ -119,6 +119,12 @@ def reconstruction(target: Target, operation: str, out):
     return rec, sum(len(b) for b in blobs)
 
 
+def raw_bytes(config: dict) -> int:
+    """The f32 bytes of one call's fields: the rate's numerator per call
+    and the ratio's per stream set."""
+    return int(config["fields_per_call"]) * spec.grid_points(config) * 4
+
+
 def _profile_options():
     import jax
     opts = jax.profiler.ProfileOptions()
@@ -177,7 +183,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
         jax.profiler.stop_trace()
     mem = [footprint(d) for d in devices]
     peak = max(in_use + reserved for in_use, reserved in mem)
-    raw = n * shape[0] * shape[1] * 4
+    raw = raw_bytes(cfg)
 
     rec, stored_bytes = reconstruction(target, op, out)
     checks = reference.readings(fields, rec, eb, cfg["guarantees"])

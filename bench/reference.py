@@ -11,7 +11,9 @@ to the guarantees its configuration states:
   magnitude.
 * ``fp``, ``ft``: false critical points and false types against the
   original field's critical points, from this module's own classifier
-  (4 neighbours, strict comparisons, the paper's CD definition).
+  (strict comparisons; on a 2-D field 4 neighbours, the paper's CD
+  definition; on a 3-D volume 6 neighbours, the repo's axis-by-axis
+  extension of it, since the paper defines no 3-D critical points).
 * ``fn_share``: critical points lost by the reconstruction, as a share of
   those lost by the plain linear quantizer at the same bound
   (``2 eb * floor((x + eb) / 2eb)``, SZp's reconstruction).  TopoSZp's
@@ -31,7 +33,17 @@ REGULAR, MINIMUM, SADDLE, MAXIMUM = 0, 1, 2, 3
 
 
 def classify(f: jnp.ndarray) -> jnp.ndarray:
-    """Critical-point labels of a 2-D field (int32, values above).
+    """Critical-point labels (int32, values above) of a 2-D field or a 3-D
+    volume, by its rank."""
+    if f.ndim == 2:
+        return _classify2(f)
+    if f.ndim == 3:
+        return _classify3(f)
+    raise ValueError(f"field of shape {f.shape} is neither 2-D nor 3-D")
+
+
+def _classify2(f: jnp.ndarray) -> jnp.ndarray:
+    """Critical-point labels of a 2-D field.
 
     A point is a minimum (maximum) when every neighbour it has among
     up, down, left and right is strictly higher (lower); corners have two
@@ -69,6 +81,51 @@ def classify(f: jnp.ndarray) -> jnp.ndarray:
     return lab.astype(jnp.int32)
 
 
+def _neighbours(f: jnp.ndarray, axis: int, missing: float):
+    """The previous and the next value along ``axis`` of every point,
+    ``missing`` where the point has no such neighbour."""
+    n = f.shape[axis]
+    edge = list(f.shape)
+    edge[axis] = 1
+    pad = jnp.full(edge, missing, f.dtype)
+    prev = jnp.concatenate(
+        [pad, jax.lax.slice_in_dim(f, 0, n - 1, axis=axis)], axis=axis)
+    nxt = jnp.concatenate(
+        [jax.lax.slice_in_dim(f, 1, n, axis=axis), pad], axis=axis)
+    return prev, nxt
+
+
+def _classify3(f: jnp.ndarray) -> jnp.ndarray:
+    """Critical-point labels of a 3-D volume, over the 6 axis neighbours.
+
+    The paper defines critical points in 2-D only; this is the repo's
+    axis-by-axis extension of its definition.  A point is a minimum
+    (maximum) when every axis neighbour it has is strictly higher
+    (lower).  A point is a saddle when, along each of the three axes,
+    both neighbours exist and lie strictly on one side of it, and the
+    three axes do not all lie on the same side.  Saddles need both
+    neighbours on every axis, so only interior points can be saddles."""
+    f = f.astype(jnp.float32)
+    inf = jnp.float32(jnp.inf)
+    is_min = is_max = True
+    pair_hi, pair_lo = [], []
+    for axis in range(3):
+        prev_hi, next_hi = _neighbours(f, axis, inf)      # missing: +inf
+        prev_lo, next_lo = _neighbours(f, axis, -inf)     # missing: -inf
+        is_min = is_min & (prev_hi > f) & (next_hi > f)
+        is_max = is_max & (prev_lo < f) & (next_lo < f)
+        pair_hi.append((prev_lo > f) & (next_lo > f))
+        pair_lo.append((prev_hi < f) & (next_hi < f))
+    one_sided = [hi | lo for hi, lo in zip(pair_hi, pair_lo)]
+    is_saddle = (one_sided[0] & one_sided[1] & one_sided[2]
+                 & ~(pair_hi[0] & pair_hi[1] & pair_hi[2])
+                 & ~(pair_lo[0] & pair_lo[1] & pair_lo[2]))
+    lab = jnp.where(is_saddle, SADDLE, REGULAR)
+    lab = jnp.where(is_min, MINIMUM, lab)
+    lab = jnp.where(is_max, MAXIMUM, lab)
+    return lab.astype(jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("bound_eb",))
 def _field_counts(orig, rec, eb, bound_eb: float):
     """Per-field readings: max|err|, max|x|, FP, FT, FN, FN of the plain
@@ -92,7 +149,8 @@ def _field_counts(orig, rec, eb, bound_eb: float):
 
 def readings(orig: jnp.ndarray, rec: jnp.ndarray, eb: float,
              guarantees: dict) -> dict:
-    """Compare a call's reconstruction (N, ny, nx) with its fields.
+    """Compare a call's reconstruction (N, ny, nx) or (N, nz, ny, nx)
+    with its fields.
 
     Runs field by field so that it fits beside whatever the process holds.
     Returns ``{name: {"value": v, "limit": l}}`` for every guarantee the

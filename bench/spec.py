@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import math
 import os
 from typing import NamedTuple
 
@@ -28,6 +29,12 @@ class Cell(NamedTuple):
     traffic: dict
     end_to_end: list     # BENCHMARK.json entries this cell reports
     per_layer: list
+
+
+def grid_points(config: dict) -> int:
+    """Points of one field of the configuration's grid, whatever its rank:
+    (ny, nx) or (nz, ny, nx)."""
+    return math.prod(int(s) for s in config["grid"])
 
 
 def load_benchmark(root: str = ROOT) -> dict:

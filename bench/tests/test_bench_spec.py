@@ -51,24 +51,37 @@ def test_unknown_cell_is_an_error():
 
 
 def test_a_cell_and_a_metric_are_added_by_files_alone(tmp_path):
-    """A new traffic mix, cell and reader need new files and entries only."""
+    """A new configuration (here a 3-D grid), traffic mix, cell and reader
+    need new files and entries only."""
     root = tmp_path / "co"
     shutil.copytree(os.path.join(spec.ROOT, "bench"), root / "bench")
     bench = json.loads(json.dumps(BENCH))
-    bench["workloads"].append({"name": "atm_szp.decompress",
-                               "config": "atm_szp", "traffic": "decompress",
+    cfg = dict(spec.find_cell("atm_szp.decompress").config,
+               name="vol_szp", grid=[100, 500, 500], fields_per_call=4)
+    (root / "bench" / "configs" / "vol_szp.json").write_text(json.dumps(cfg))
+    (root / "bench" / "traffic" / "decompress_grf.json").write_text(
+        json.dumps({"operation": "decompress", "loop": "closed",
+                    "clients": 1, "fields": [{"generator": "grf",
+                                              "power": 3.0}]}))
+    bench["configs"].append({"name": "vol_szp", "source": "x",
+                             "file": "bench/configs/vol_szp.json",
+                             "reduced": [], "why": "x"})
+    bench["workloads"].append({"name": "vol_szp.decompress_grf",
+                               "config": "vol_szp",
+                               "traffic": "decompress_grf",
                                "chips": 1, "why": "x"})
     for m in bench["end_to_end"]:
         if m["name"] == "decompress_GBps":
-            m["workloads"].append("atm_szp.decompress")
+            m["workloads"].append("vol_szp.decompress_grf")
     bench["per_layer"].append({"name": "new_ms", "unit": "ms",
                                "better": "lower", "source": "device_trace",
                                "layer": "x", "moves": "decompress_GBps"})
     (root / "BENCHMARK.json").write_text(json.dumps(bench))
     (root / "bench" / "metrics" / "new_ms.py").write_text(
         "def read(ctx):\n    return 1.5\n")
-    cell = spec.find_cell("atm_szp.decompress", str(root))
+    cell = spec.find_cell("vol_szp.decompress_grf", str(root))
     assert cell.config["compressor"] == "szp"
+    assert spec.grid_points(cell.config) == 25_000_000
     assert cell.traffic["operation"] == "decompress"
     # no workloads key: reported wherever the metric it moves is
     assert [m["name"] for m in cell.per_layer] == ["new_ms"]

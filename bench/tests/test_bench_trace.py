@@ -1,7 +1,9 @@
 """The trace reduction on hand-built events and a hand-encoded xplane."""
+import math
+
 import pytest
 
-from bench import devtrace
+from bench import devtrace, harness
 from bench.devtrace import Op, Reduction, Span
 
 STAGE = "jit(_compress_measure_batch)/vmap(toposzp.stage_detect)/sort:"
@@ -68,6 +70,23 @@ def test_per_field_division_and_idle_share():
         53e-9 * 1000 / 8)
     assert ctx.ms_per_field("toposzp.stage_restore") is None
     assert ctx.idle_pct() == pytest.approx(27.0)
+
+
+@pytest.mark.parametrize("grid", [[1800, 3600], [100, 500, 500], [7, 9]])
+def test_points_and_raw_bytes_take_every_axis_of_the_grid(grid):
+    class Cell:
+        config = {"grid": grid, "fields_per_call": 4,
+                  "compressor": "toposzp"}
+        traffic = {"operation": "compress"}
+    ctx = devtrace.Context(_red(), Cell, "TPU v5 lite")
+    assert ctx.n_points == math.prod(grid)
+    assert harness.raw_bytes(Cell.config) == 4 * math.prod(grid) * 4
+    if grid == [1800, 3600]:           # the 2-D cells read what they did
+        assert ctx.n_points == 6_480_000
+        assert harness.raw_bytes(Cell.config) == 103_680_000
+    if grid == [100, 500, 500]:
+        assert ctx.n_points == 25_000_000
+        assert harness.raw_bytes(Cell.config) == 400_000_000
 
 
 def test_self_times_leave_out_nested_time():
