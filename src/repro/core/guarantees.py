@@ -26,8 +26,13 @@ _MAX_ITERS = 32
 
 
 def _dilate(mask: jnp.ndarray) -> jnp.ndarray:
-    """4-neighborhood dilation of a boolean mask (plus the mask itself)."""
+    """4-neighborhood (6 in a volume) dilation of a boolean mask (plus the
+    mask itself)."""
     p = jnp.pad(mask, 1, mode="constant", constant_values=False)
+    if mask.ndim == 3:
+        c = slice(1, -1)
+        return (mask | p[:-2, c, c] | p[2:, c, c] | p[c, :-2, c]
+                | p[c, 2:, c] | p[c, c, :-2] | p[c, c, 2:])
     return (mask | p[:-2, 1:-1] | p[2:, 1:-1] | p[1:-1, :-2] | p[1:-1, 2:])
 
 

@@ -4,6 +4,10 @@ The jit-side pipeline keeps the sections as separate fixed-capacity arrays;
 this module materializes the actual byte stream (header + sections in Fig. 6
 order, payload sliced to its valid length) and parses it back.  Used by the
 checkpoint manager and by the true-size accounting in the benchmarks.
+
+The header carries the grid's extent: a 2-D field's stream is version 1
+(``ny, nx``), a 3-D volume's version 2 (``nz, ny, nx``, 4 bytes more);
+``deserialize_*`` returns the 2- or 3-tuple it read.
 """
 from __future__ import annotations
 
@@ -19,8 +23,11 @@ from repro.core.toposzp import TopoSZpCompressed
 
 MAGIC = b"SZPJ"
 MAGIC_TOPO = b"TSZP"
-STREAM_VERSION = 1
-_HDR = struct.Struct("<4sIIIIdI")  # magic, version, ny, nx, block, eb, nblocks
+# stream version by the grid's rank, and its header layout
+STREAM_VERSION = {2: 1, 3: 2}
+_HDR = struct.Struct("<4sIIIIdI")   # magic, version, ny, nx, block, eb, nblocks
+_HDR3 = struct.Struct("<4sIIIIIdI")  # magic, version, nz, ny, nx, block, ...
+_HEADERS = {1: _HDR, 2: _HDR3}
 
 
 class BadStreamError(ValueError):
@@ -40,12 +47,15 @@ def _np(a) -> np.ndarray:
     return np.asarray(a)
 
 
-def serialize_szp(parts: SZpParts, shape: Tuple[int, int], eb: float,
+def serialize_szp(parts: SZpParts, shape: Tuple[int, ...], eb: float,
                   block: int = DEFAULT_BLOCK, magic: bytes = MAGIC) -> bytes:
-    ny, nx = shape
+    shape = tuple(int(s) for s in shape)
+    if len(shape) not in STREAM_VERSION:
+        raise ValueError(f"a stream holds a 2-D or 3-D grid, got {shape}")
+    ver = STREAM_VERSION[len(shape)]
     nblocks = int(_np(parts.widths).shape[0])
     payload = _np(parts.payload)[: int(parts.payload_nbytes)]
-    hdr = _HDR.pack(magic, 1, ny, nx, block, float(eb), nblocks)
+    hdr = _HEADERS[ver].pack(magic, ver, *shape, block, float(eb), nblocks)
     return b"".join([
         hdr,
         _np(parts.const_bits).tobytes(),
@@ -56,15 +66,20 @@ def serialize_szp(parts: SZpParts, shape: Tuple[int, int], eb: float,
     ])
 
 
-def deserialize_szp(buf: bytes) -> Tuple[SZpParts, Tuple[int, int], float, int]:
+def deserialize_szp(buf: bytes
+                    ) -> Tuple[SZpParts, Tuple[int, ...], float, int]:
     if len(buf) < _HDR.size:
         raise BadStreamError(f"stream too short ({len(buf)} bytes)")
-    magic, ver, ny, nx, block, eb, nblocks = _HDR.unpack_from(buf, 0)
+    magic, ver = struct.unpack_from("<4sI", buf, 0)
     if magic not in (MAGIC, MAGIC_TOPO):
         raise BadStreamError(f"bad magic {magic!r}")
-    if ver != STREAM_VERSION:
+    if ver not in _HEADERS:
         raise BadStreamError(f"unsupported stream version {ver}")
-    off = _HDR.size
+    hdr = _HEADERS[ver]
+    if len(buf) < hdr.size:
+        raise BadStreamError(f"stream too short ({len(buf)} bytes)")
+    *shape, block, eb, nblocks = hdr.unpack_from(buf, 0)[2:]
+    off = hdr.size
     n_const = -(-nblocks // 8)
     n_sign = -(-(nblocks * block) // 8)
     const_bits = np.frombuffer(buf, np.uint8, n_const, off); off += n_const
@@ -87,7 +102,7 @@ def deserialize_szp(buf: bytes) -> Tuple[SZpParts, Tuple[int, int], float, int]:
                      jnp.asarray(signs), jnp.asarray(first.copy()),
                      jnp.asarray(pay), jnp.int32(payload.shape[0]),
                      jnp.int32(len(buf)))
-    return parts, (ny, nx), eb, block
+    return parts, tuple(shape), eb, block
 
 
 def _trim_rank_parts(parts: SZpParts, n_cp: int, block: int) -> SZpParts:
@@ -102,7 +117,7 @@ def _trim_rank_parts(parts: SZpParts, n_cp: int, block: int) -> SZpParts:
         parts.payload, parts.payload_nbytes, parts.nbytes)
 
 
-def serialize_toposzp(comp: TopoSZpCompressed, shape: Tuple[int, int],
+def serialize_toposzp(comp: TopoSZpCompressed, shape: Tuple[int, ...],
                       eb: float, block: int = DEFAULT_BLOCK) -> bytes:
     base = serialize_szp(comp.szp, shape, eb, block, magic=MAGIC_TOPO)
     labels = _np(comp.labels2b).tobytes()

@@ -24,7 +24,7 @@ from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
-from repro.core.critical_points import SADDLE, classify
+from repro.core.critical_points import SADDLE, axis_neighbors, classify
 from repro.kernels import ops
 
 MAX_RADIUS = 3  # static gather window 7x7; effective radius is dynamic
@@ -48,10 +48,25 @@ def _offsets(radius: int = MAX_RADIUS) -> Tuple[jnp.ndarray, jnp.ndarray]:
     return dy.reshape(k * k), dx.reshape(k * k)
 
 
+def _local_range3(field: jnp.ndarray) -> jnp.ndarray:
+    """max - min over each point's 3x3x3 window of a volume (edge-replicated),
+    one axis at a time."""
+    hi = lo = field
+    for ax in range(3):
+        hp, hn, _, _ = axis_neighbors(hi, ax)
+        lp, ln, _, _ = axis_neighbors(lo, ax)
+        hi = jnp.maximum(hi, jnp.maximum(hp, hn))
+        lo = jnp.minimum(lo, jnp.minimum(lp, ln))
+    return hi - lo
+
+
 def adaptive_params(field: jnp.ndarray, eb: float):
     """(sigma map, radius map) from local / global variation heuristics."""
-    patches = _window_patches(field, 1)                  # 3x3 local variation
-    local_var = patches.max(-1) - patches.min(-1)
+    if field.ndim == 3:
+        local_var = _local_range3(field)                 # 3x3x3 variation
+    else:
+        patches = _window_patches(field, 1)              # 3x3 local variation
+        local_var = patches.max(-1) - patches.min(-1)
     scale = jnp.maximum(field.max() - field.min(), 1e-30)
     nv = jnp.clip(local_var / scale, 0.0, 1.0)           # normalized variation
     sigma = 1.0 - 0.5 * nv                               # in [0.5, 1.0]
@@ -121,9 +136,13 @@ def refine_saddles(recon: jnp.ndarray, labels: jnp.ndarray, eb: float,
     kernels.ops backend runs the separable global-parameter Shepard kernel
     (``rbf_mode="shepard"`` only — "interp" always takes the jnp solve).
     The 2*eb clamp and the restored-saddle check are identical either way,
-    so the TopoSZp guarantees are estimator-independent.
+    so the TopoSZp guarantees are estimator-independent.  A 3-D volume
+    takes the kernel path only (its third pass runs along z).
     """
     recon = recon.astype(jnp.float32)
+    if recon.ndim == 3 and (backend is None or rbf_mode != "shepard"):
+        raise ValueError("a 3-D volume's saddles are refined by the "
+                         "separable Shepard kernel: pass a backend")
     if backend is not None and rbf_mode == "shepard":
         cur = ops.cp_detect(recon, backend=backend)
         lost = (labels == SADDLE) & (cur != SADDLE)

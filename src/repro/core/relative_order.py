@@ -39,12 +39,14 @@ def compute_ranks(field: jnp.ndarray, labels: jnp.ndarray,
     """Per-point rank among same-(bin, type) critical points.
 
     Args:
-      field:  (ny, nx) float32 original values.
-      labels: (ny, nx) int32 CD labels.
-      codes:  (ny, nx) int32 quantization bin indices.
+      field:  float32 original values, of any shape ((ny, nx) or a
+              (nz, ny, nx) volume).
+      labels: int32 CD labels, same shape.
+      codes:  int32 quantization bin indices, same shape.
 
     Returns:
-      (ny, nx) int32 ranks; 0 at regular points, >= 1 at critical points.
+      int32 ranks of the field's shape; 0 at regular points, >= 1 at
+      critical points.
 
     Points are ordered by (bin, secondary value, index) with two stable
     single-key int32 sorts (least significant key first) — on the TPU a

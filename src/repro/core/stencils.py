@@ -29,9 +29,10 @@ def apply_extrema_stencils(recon: jnp.ndarray, labels: jnp.ndarray,
     """Restore lost extrema on the SZp reconstruction.
 
     Args:
-      recon:  (ny, nx) SZp-decompressed field (|recon - orig| <= eb).
-      labels: (ny, nx) original CD labels from the stream.
-      ranks:  (ny, nx) same-bin ranks from the stream (delta in the paper).
+      recon:  SZp-decompressed field (|recon - orig| <= eb), (ny, nx) or
+              a (nz, ny, nx) volume (6-neighbourhood).
+      labels: original CD labels from the stream, same shape.
+      ranks:  same-bin ranks from the stream (delta in the paper).
       eb:     the user error bound eps (correction budget is +-eb on top).
       backend: None keeps the legacy pure-jnp math; a kernels.ops backend
         dispatches the CP^ reclassification and the fused extrema stencil

@@ -17,7 +17,7 @@ the per-block widths, the max width is lifted to a static
 capacity — ``B*ceil(K*w_bucket/8)`` bytes instead of the 32-bit worst case
 (typically 4-8x less buffer and gather work).  ``compress_codes`` /
 ``decompress_codes`` keep the one-shot jit-able worst-case form for
-callers that embed them in a larger jit (core/baselines.py, core/topo3d.py)
+callers that embed them in a larger jit (core/baselines.py)
 and for the lossless integer mode (the TopoSZp rank metadata).
 """
 from __future__ import annotations

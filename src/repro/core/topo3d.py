@@ -1,184 +1,41 @@
-"""TopoSZp-3D — beyond-paper extension (the paper's stated future work).
+"""TopoSZp on 3-D volumes — names kept for one-field callers.
 
-Generalizes the pipeline to 3-D scalar fields with the 6-neighborhood:
-  minima/maxima: all existing axis neighbors strictly higher/lower;
-  saddle: interior point where each axis pair lies on one strict side and
-  the axes disagree (the direct generalization of the 2-D definition).
-
-Reuses the SZp substrate (QZ + B/LZ + BE) on the flattened field, the 2-bit
-label map, the sparse CP-first rank stream, and the delta-ULP extrema
-stencils with FP/FT suppression.  Saddle restoration is extrema-free in 3-D
-v1 (no RBF): suppression still guarantees FP = FT = 0 and the 2-eps bound.
+A volume runs through the normal pipeline (``core.toposzp``): its entry
+points take (nz, ny, nx) and pick the 6-neighbour CD, CP^+RP^ and RS^
+kernels by the field's rank.  The 6-neighbourhood (the repo's extension;
+the paper defines critical points in 2-D only) is defined in
+``core.critical_points``.
 """
 from __future__ import annotations
 
-import functools
 from typing import Sequence
 
-import jax
 import jax.numpy as jnp
 
-from repro.core import bitpack
-from repro.core.quantize import dequantize, quantize
-from repro.core.relative_order import compute_ranks
-from repro.core.szp import (DEFAULT_BLOCK, compress_codes,
-                            decompress_codes)
-from repro.core.toposzp import (TopoSZpCompressed, _cp_first_dest,
-                                rank_stream_bytes)
-from repro.utils import ulp_step
+from repro.core.critical_points import (MAXIMA, MINIMA, REGULAR, SADDLE,
+                                        classify)
+from repro.core.szp import DEFAULT_BLOCK
+from repro.core.toposzp import (TopoSZpCompressed, toposzp_compress,
+                                toposzp_decompress)
 
-REGULAR, MINIMA, SADDLE, MAXIMA = 0, 1, 2, 3
-_AXES = (0, 1, 2)
-
-
-def _axis_neighbors(f: jnp.ndarray, axis: int):
-    """(prev, next, has_prev, has_next) along one axis (edge-replicated)."""
-    n = f.shape[axis]
-    pad = [(0, 0)] * 3
-    pad[axis] = (1, 1)
-    p = jnp.pad(f, pad, mode="edge")
-    sl_prev = [slice(None)] * 3
-    sl_prev[axis] = slice(0, n)
-    sl_next = [slice(None)] * 3
-    sl_next[axis] = slice(2, n + 2)
-    idx = jnp.arange(n)
-    shape = [1, 1, 1]
-    shape[axis] = n
-    ii = idx.reshape(shape)
-    has_prev = jnp.broadcast_to(ii > 0, f.shape)
-    has_next = jnp.broadcast_to(ii < n - 1, f.shape)
-    return p[tuple(sl_prev)], p[tuple(sl_next)], has_prev, has_next
+__all__ = ["REGULAR", "MINIMA", "SADDLE", "MAXIMA", "classify3d",
+           "toposzp3d_compress", "toposzp3d_decompress"]
 
 
 def classify3d(field: jnp.ndarray) -> jnp.ndarray:
-    """6-neighbor label map for a 3-D field -> int32 {0,1,2,3}."""
-    f = field.astype(jnp.float32)
-    all_hi = jnp.ones(f.shape, bool)
-    all_lo = jnp.ones(f.shape, bool)
-    interior = jnp.ones(f.shape, bool)
-    pair_hi, pair_lo = [], []
-    for ax in _AXES:
-        pv, nx, hp, hn = _axis_neighbors(f, ax)
-        all_hi &= jnp.where(hp, pv > f, True) & jnp.where(hn, nx > f, True)
-        all_lo &= jnp.where(hp, pv < f, True) & jnp.where(hn, nx < f, True)
-        interior &= hp & hn
-        pair_hi.append((pv > f) & (nx > f))
-        pair_lo.append((pv < f) & (nx < f))
-
-    # saddle: every axis pair strictly one-sided, and axes disagree
-    one_sided = ((pair_hi[0] | pair_lo[0]) & (pair_hi[1] | pair_lo[1])
-                 & (pair_hi[2] | pair_lo[2]))
-    all_same_hi = pair_hi[0] & pair_hi[1] & pair_hi[2]
-    all_same_lo = pair_lo[0] & pair_lo[1] & pair_lo[2]
-    is_saddle = interior & one_sided & ~all_same_hi & ~all_same_lo
-
-    lab = jnp.where(all_lo, MAXIMA, REGULAR)
-    lab = jnp.where(is_saddle, SADDLE, lab)
-    lab = jnp.where(all_hi, MINIMA, lab)
-    return lab.astype(jnp.int32)
+    """6-neighbour label map of a 3-D volume -> int32 {0,1,2,3}."""
+    if field.ndim != 3:
+        raise ValueError(f"expected a (nz, ny, nx) volume, got {field.shape}")
+    return classify(field)
 
 
-def _neighbor_min_max3d(f: jnp.ndarray):
-    big = jnp.float32(jnp.inf)
-    nmin = jnp.full(f.shape, big)
-    nmax = jnp.full(f.shape, -big)
-    for ax in _AXES:
-        pv, nx, hp, hn = _axis_neighbors(f, ax)
-        nmin = jnp.minimum(nmin, jnp.minimum(jnp.where(hp, pv, big),
-                                             jnp.where(hn, nx, big)))
-        nmax = jnp.maximum(nmax, jnp.maximum(jnp.where(hp, pv, -big),
-                                             jnp.where(hn, nx, -big)))
-    return nmin, nmax
-
-
-def _dilate3d(mask: jnp.ndarray) -> jnp.ndarray:
-    out = mask
-    for ax in _AXES:
-        pv, nx, hp, hn = _axis_neighbors(mask, ax)
-        out = out | (pv & hp) | (nx & hn)
-    return out
-
-
-@functools.partial(jax.jit, static_argnames=("block",))
 def toposzp3d_compress(field: jnp.ndarray, eb: float,
                        block: int = DEFAULT_BLOCK) -> TopoSZpCompressed:
-    field = field.astype(jnp.float32)
-    codes = quantize(field, eb)
-    labels = classify3d(field)
-    ranks = compute_ranks(field.reshape(1, -1), labels.reshape(1, -1),
-                          codes.reshape(1, -1)).reshape(field.shape)
-
-    szp_parts = compress_codes(codes.reshape(-1), block=block)
-    labels_flat = labels.reshape(-1)
-    labels2b = bitpack.pack_2bit(labels_flat)
-    n_cp = (labels_flat != 0).sum().astype(jnp.int32)
-    dest = _cp_first_dest(labels_flat)
-    ranks_sorted = jnp.zeros(labels_flat.shape[0], jnp.int32).at[dest].set(
-        ranks.reshape(-1), unique_indices=True)
-    rank_parts = compress_codes(ranks_sorted, block=block)
-    nbytes = (szp_parts.nbytes + labels2b.shape[0]
-              + rank_stream_bytes(n_cp, rank_parts.payload_nbytes, block))
-    return TopoSZpCompressed(szp_parts, labels2b, rank_parts, n_cp,
-                             nbytes.astype(jnp.int32))
+    """One volume through :func:`toposzp_compress`."""
+    return toposzp_compress(field, eb, block=block)
 
 
-@functools.partial(jax.jit, static_argnames=("shape", "block"))
 def toposzp3d_decompress(comp: TopoSZpCompressed, shape: Sequence[int],
                          eb: float, block: int = DEFAULT_BLOCK) -> jnp.ndarray:
-    nz, ny, nx = shape
-    n = nz * ny * nx
-    codes = decompress_codes(comp.szp, n, block=block)
-    base = dequantize(codes, eb).reshape(shape)
-
-    labels_flat = bitpack.unpack_2bit(comp.labels2b, n)
-    labels = labels_flat.reshape(shape)
-    n_codes = comp.ranks.widths.shape[0] * block
-    rs = decompress_codes(comp.ranks, min(n_codes, n), block=block)
-    if n_codes < n:
-        rs = jnp.concatenate([rs, jnp.zeros(n - n_codes, jnp.int32)])
-    dest = _cp_first_dest(labels_flat)
-    ranks = rs[:n][dest].reshape(shape)
-
-    # extrema stencils (6-neighbor) + rank separation
-    cur = classify3d(base)
-    lost_min = (labels == MINIMA) & (cur != MINIMA)
-    lost_max = (labels == MAXIMA) & (cur != MAXIMA)
-    nmin, nmax = _neighbor_min_max3d(base)
-    delta = jnp.maximum(ranks, 1)
-    tgt_min = ulp_step(nmin, -delta)
-    tgt_max = ulp_step(nmax, +delta)
-    ok_min = lost_min & (tgt_min >= base - eb) & (tgt_min <= base + eb)
-    ok_max = lost_max & (tgt_max >= base - eb) & (tgt_max <= base + eb)
-    cand = jnp.where(ok_min, tgt_min, base)
-    cand = jnp.where(ok_max, tgt_max, cand)
-    survive = (labels != REGULAR) & ~(ok_min | ok_max)
-    sep = jnp.where(labels == MINIMA, -delta, delta)
-    cand = jnp.where(survive, ulp_step(cand, sep), cand)
-
-    # FP/FT suppression (same fixed-point loop as 2-D)
-    keep0 = cand != base
-
-    def viol(fld):
-        lbl = classify3d(fld)
-        return (lbl != REGULAR) & (lbl != labels)
-
-    def cond(state):
-        keep, it = state
-        return jnp.any(viol(jnp.where(keep, cand, base))) & (it < 32)
-
-    def body(state):
-        keep, it = state
-        v = viol(jnp.where(keep, cand, base))
-        return keep & ~_dilate3d(v), it + 1
-
-    keep, _ = jax.lax.while_loop(cond, body, (keep0, jnp.int32(0)))
-    return jnp.where(keep, cand, base)
-
-
-def false_cases3d(orig, recon):
-    lo, lr = classify3d(orig), classify3d(recon)
-    fn = (lo != REGULAR) & (lr == REGULAR)
-    fp = (lo == REGULAR) & (lr != REGULAR)
-    ft = (lo != REGULAR) & (lr != REGULAR) & (lo != lr)
-    return {"FN": int(fn.sum()), "FP": int(fp.sum()), "FT": int(ft.sum()),
-            "n_cp": int((lo != REGULAR).sum())}
+    """One volume through :func:`toposzp_decompress`."""
+    return toposzp_decompress(comp, shape, eb, block=block)

@@ -17,6 +17,10 @@ Stream bytes are bit-identical across all backends.  The rank stream
 (section 7) must stay lossless, so its decode always takes the exact
 int32-cumsum path regardless of backend.
 
+Every entry point takes a 2-D field (ny, nx) or a 3-D volume (nz, ny,
+nx); CD, CP^+RP^ and RS^ pick their 4- or 6-neighbour kernels by the
+field's rank, the rest of the pipeline works on the flattened field.
+
 ``toposzp_compress_batch`` / ``toposzp_decompress_batch`` stack N
 same-shape fields into ONE compiled call (compress: vmap = grid over the
 batch dim; decompress: a loop over the fields), so multi-field workloads (checkpoint shards, the fig7 bench) stop paying a
@@ -25,6 +29,7 @@ dispatch + trace per field.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -99,7 +104,8 @@ def _compress_measure(field: jnp.ndarray, eb: float, block: int,
 
     # --- CD + RP (the lightweight topology stage, before lossy QZ) ---
     with jax.named_scope("toposzp.stage_detect"):
-        labels = ops.cp_detect(field, backend=backend)
+        with jax.named_scope("toposzp.stage_cd"):
+            labels = ops.cp_detect(field, backend=backend)
         with jax.named_scope("toposzp.stage_rp"):
             ranks = compute_ranks(field, labels, codes)
 
@@ -191,20 +197,39 @@ def _compress_resident(measure, fields, eb, block: int, backend: str,
                           backend=backend, batched=batched)
 
 
+def _check_rank(shape, batched: bool) -> int:
+    """The grid's rank (2 or 3) of a field or a batch of fields; raises
+    before anything is traced otherwise."""
+    rank = len(shape) - batched
+    if rank not in (2, 3):
+        want = "(N, ny, nx) or (N, nz, ny, nx)" if batched else \
+            "(ny, nx) or (nz, ny, nx)"
+        raise ValueError(f"expected {want} fields, got shape {tuple(shape)}")
+    return rank
+
+
+def _count_points(shape) -> None:
+    """Counter ``toposzp.compress.points``: points compressed by a call,
+    from its static shape (a per-point reading of any other counter)."""
+    obs.counter_add("toposzp.compress.points", math.prod(shape))
+
+
 def toposzp_compress(field: jnp.ndarray, eb,
                      block: int = DEFAULT_BLOCK,
                      backend: Optional[str] = None, resident: bool = False,
                      donate: bool = False) -> TopoSZpCompressed:
-    """Compress a 2-D scalar field with topology metadata.
+    """Compress a 2-D scalar field or a 3-D volume with topology metadata.
 
     ``resident=True`` runs the whole compress on device (``lax.switch``
     bucket select; composes under an enclosing ``jax.jit``; worst-case
     payload capacity) with streams byte-identical to the classic two-pass
     path; ``donate=True`` (resident only) donates the field's buffer."""
+    rank = _check_rank(field.shape, batched=False)
     backend = ops.resolve_backend(backend)
+    _count_points(field.shape)
     if resident:
         with obs.span("compress.resident", pipeline="toposzp",
-                      backend=backend):
+                      backend=backend, rank=rank):
             with _quiet_donation():
                 comp = _compress_resident(
                     _measure_one_donated if donate else _measure_one,
@@ -212,16 +237,16 @@ def toposzp_compress(field: jnp.ndarray, eb,
         _obs_stream(comp.szp, "toposzp")
         return comp
     with obs.span("compress.quant", pipeline="toposzp", backend=backend,
-                  includes="detect+quant"):
-        main, rank, labels2b, n_cp, w_max, rw_max = _measure_one(
+                  includes="detect+quant", rank=rank):
+        main, rstream, labels2b, n_cp, w_max, rw_max = _measure_one(
             field, eb, block=block, backend=backend)
         # one blocking read for both width maxes
         wm, rwm = np.asarray(jnp.stack([w_max, rw_max]))
         mw_main = bitpack.width_bucket(int(wm))
         mw_rank = bitpack.width_bucket(int(rwm))
     with obs.span("compress.pack", pipeline="toposzp",
-                  width_bucket=mw_main, rank_bucket=mw_rank):
-        comp = _pack_streams(main, rank, labels2b, n_cp, block=block,
+                  width_bucket=mw_main, rank_bucket=mw_rank, rank=rank):
+        comp = _pack_streams(main, rstream, labels2b, n_cp, block=block,
                              mw_main=mw_main, mw_rank=mw_rank,
                              backend=backend)
     _obs_stream(comp.szp, "toposzp")
@@ -236,7 +261,8 @@ def toposzp_compress_batch(fields: jnp.ndarray, eb,
                            donate: bool = False) -> TopoSZpCompressed:
     """Compress N stacked same-shape fields in one compiled call.
 
-    ``fields`` is (N, ny, nx); every array of the result carries a leading
+    ``fields`` is (N, ny, nx) or (N, nz, ny, nx); any other rank raises
+    ``ValueError``.  Every array of the result carries a leading
     batch axis.  Streams are byte-identical to N per-field calls (the
     shared capacity bucket covers the batch max width; valid bytes are
     unaffected).  Use :func:`batch_slice` / :func:`serialize` helpers to
@@ -245,12 +271,12 @@ def toposzp_compress_batch(fields: jnp.ndarray, eb,
     one reduce over the whole batch (a single scalar-pair read, not N
     per-field syncs).
     """
-    if fields.ndim != 3:
-        raise ValueError(f"expected (N, ny, nx) fields, got {fields.shape}")
+    rank = _check_rank(fields.shape, batched=True)
     backend = ops.resolve_backend(backend)
+    _count_points(fields.shape)
     if resident:
         with obs.span("compress.resident", pipeline="toposzp",
-                      backend=backend, batch=fields.shape[0]):
+                      backend=backend, batch=fields.shape[0], rank=rank):
             with _quiet_donation():
                 comp = _compress_resident(
                     _measure_batch_donated if donate else _measure_batch,
@@ -258,15 +284,15 @@ def toposzp_compress_batch(fields: jnp.ndarray, eb,
         _obs_stream(comp.szp, "toposzp")
         return comp
     with obs.span("compress.quant", pipeline="toposzp", backend=backend,
-                  includes="detect+quant", batch=fields.shape[0]):
-        main, rank, labels2b, n_cp, w_max, rw_max = _measure_batch(
+                  includes="detect+quant", batch=fields.shape[0], rank=rank):
+        main, rstream, labels2b, n_cp, w_max, rw_max = _measure_batch(
             fields, eb, block=block, backend=backend)
         wm, rwm = np.asarray(jnp.stack([w_max, rw_max]))
         mw_main = bitpack.width_bucket(int(wm))
         mw_rank = bitpack.width_bucket(int(rwm))
     with obs.span("compress.pack", pipeline="toposzp",
-                  width_bucket=mw_main, rank_bucket=mw_rank):
-        comp = _pack_streams(main, rank, labels2b, n_cp, block=block,
+                  width_bucket=mw_main, rank_bucket=mw_rank, rank=rank):
+        comp = _pack_streams(main, rstream, labels2b, n_cp, block=block,
                              mw_main=mw_main, mw_rank=mw_rank,
                              backend=backend, batched=True)
     _obs_stream(comp.szp, "toposzp")
@@ -312,8 +338,7 @@ def fields_as_pages(fields: jnp.ndarray, page_shape: Sequence[int],
 def _decode_field(comp: TopoSZpCompressed, shape, eb: float, block: int,
                   recon: str, deq_backend: str, backend: str):
     """BE^ -> LZ^+B^ -> QZ^ -> MD^ for one field -> (base, labels, ranks)."""
-    ny, nx = shape
-    n = ny * nx
+    n = math.prod(shape)
 
     with jax.named_scope("toposzp.stage_decode"):
         # --- QZ^ through the kernel dequant (guarded by the caller) ---
@@ -401,7 +426,8 @@ def toposzp_decompress(comp: TopoSZpCompressed, shape: Sequence[int],
                        eb, block: int = DEFAULT_BLOCK,
                        rbf_mode: str = "shepard", recon: str = "center",
                        backend: Optional[str] = None) -> jnp.ndarray:
-    """Decompress with extrema restoration + RBF saddle refinement.
+    """Decompress a 2-D field or a 3-D volume of ``shape`` with extrema
+    restoration + RBF saddle refinement.
 
     Device-resident: the 2^24 dequant-exactness guard runs as an in-graph
     ``lax.cond``, so the call never syncs to the host and composes under
@@ -412,8 +438,10 @@ def toposzp_decompress(comp: TopoSZpCompressed, shape: Sequence[int],
       * |out - orig| <= 2 eb (relaxed-but-strict bound, paper Table I)
       * zero FP, zero FT w.r.t. the original label map
     """
+    rank = _check_rank(tuple(shape), batched=False)
     backend = ops.resolve_backend(backend)
-    with obs.span("decompress", pipeline="toposzp", backend=backend):
+    with obs.span("decompress", pipeline="toposzp", backend=backend,
+                  rank=rank):
         out = _decompress_one(comp, eb, shape=tuple(shape), block=block,
                               rbf_mode=rbf_mode, recon=recon,
                               backend=backend)
@@ -426,13 +454,14 @@ def toposzp_decompress_batch(comp: TopoSZpCompressed, shape: Sequence[int],
                              rbf_mode: str = "shepard",
                              recon: str = "center",
                              backend: Optional[str] = None) -> jnp.ndarray:
-    """Decompress a batched stream -> (N, ny, nx); equal to stacking N
-    per-field :func:`toposzp_decompress` calls.  Device-resident (in-graph
-    dequant guard, no host syncs)."""
+    """Decompress a batched stream -> (N, *shape), ``shape`` (ny, nx) or
+    (nz, ny, nx); equal to stacking N per-field :func:`toposzp_decompress`
+    calls.  Device-resident (in-graph dequant guard, no host syncs)."""
+    rank = _check_rank(tuple(shape), batched=False)
     backend = ops.resolve_backend(backend)
     nb = comp.szp.widths.shape[0]
     with obs.span("decompress", pipeline="toposzp", backend=backend,
-                  batch=nb):
+                  batch=nb, rank=rank):
         out = _decompress_batch(comp, eb, shape=tuple(shape), block=block,
                                 rbf_mode=rbf_mode, recon=recon,
                                 backend=backend)

@@ -7,6 +7,8 @@ pure int32 bit ops on the VPU (see utils.ulp_step for the host version).
 
 Same shifted-operand halo pattern as cp_detect.py; fully elementwise.
 The field extent is static (baked into the body); ``eb`` rides in SMEM.
+A 3-D volume takes the 6-neighborhood (``extrema_restore3``), tiled plane
+by plane as ``cp_detect.cp_detect3`` is.
 """
 from __future__ import annotations
 
@@ -17,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.cp_detect import _shifts
+from repro.kernels.cp_detect import _shifts, _shifts3, plane_tiles
 
 DEFAULT_TY, DEFAULT_TX = 128, 128
 _INT32_MIN = -(2 ** 31)
@@ -59,6 +61,12 @@ def _restore_kernel(eb_ref, f_ref, t_ref, d_ref, l_ref, r_ref,
                        jnp.maximum(jnp.where(has_l, l, -big),
                                    jnp.where(has_r, r, -big)))
 
+    out_ref[...] = _restored(f, nmin, nmax, lab, cur, rank, eb)
+
+
+def _restored(f, nmin, nmax, lab, cur, rank, eb):
+    """Lost extrema moved ``rank`` ULPs past their neighbours' min / max,
+    where that stays within +-eb."""
     delta = jnp.maximum(rank, 1)
     tgt_min = _i2f(_f2i(nmin) - delta)
     tgt_max = _i2f(_f2i(nmax) + delta)
@@ -69,8 +77,28 @@ def _restore_kernel(eb_ref, f_ref, t_ref, d_ref, l_ref, r_ref,
     ok_max = lost_max & (tgt_max >= f - eb) & (tgt_max <= f + eb)
 
     out = jnp.where(ok_min, tgt_min, f)
-    out = jnp.where(ok_max, tgt_max, out)
-    out_ref[...] = out
+    return jnp.where(ok_max, tgt_max, out)
+
+
+def _restore3_kernel(eb_ref, f_ref, p_ref, n_ref, t_ref, d_ref, l_ref, r_ref,
+                     lab_ref, cur_ref, rank_ref, out_ref, *, nz, ny, nx):
+    f = f_ref[...]
+    kz, ti, tj = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    by, bx = f.shape
+    kk = kz + jnp.zeros((by, bx), jnp.int32)
+    ii = ti * by + jax.lax.broadcasted_iota(jnp.int32, (by, bx), 0)
+    jj = tj * bx + jax.lax.broadcasted_iota(jnp.int32, (by, bx), 1)
+    nbrs = ((p_ref[...], kk > 0), (n_ref[...], kk < nz - 1),
+            (t_ref[...], ii > 0), (d_ref[...], ii < ny - 1),
+            (l_ref[...], jj > 0), (r_ref[...], jj < nx - 1))
+    big = jnp.float32(3.4e38)
+    nmin = jnp.full((by, bx), big)
+    nmax = jnp.full((by, bx), -big)
+    for v, has in nbrs:
+        nmin = jnp.minimum(nmin, jnp.where(has, v, big))
+        nmax = jnp.maximum(nmax, jnp.where(has, v, -big))
+    out_ref[...] = _restored(f, nmin, nmax, lab_ref[...], cur_ref[...],
+                             rank_ref[...], eb_ref[0])
 
 
 @functools.partial(jax.jit, static_argnames=("ty", "tx", "interpret"))
@@ -102,3 +130,26 @@ def extrema_restore(recon: jnp.ndarray, labels: jnp.ndarray,
         interpret=interpret,
     )(ebv, f, t, d, l, r, lab, cur, rank)
     return out[:ny, :nx]
+
+
+@functools.partial(jax.jit, static_argnames=("ty", "tx", "interpret"))
+def extrema_restore3(recon: jnp.ndarray, labels: jnp.ndarray,
+                     cur_labels: jnp.ndarray, ranks: jnp.ndarray, eb: float,
+                     ty: int = DEFAULT_TY, tx: int = DEFAULT_TX,
+                     interpret: bool = False) -> jnp.ndarray:
+    """6-neighbour lost-extrema restoration of a 3-D volume."""
+    nz, ny, nx = recon.shape
+    pad, grid, spec = plane_tiles(recon.shape, ty, tx)
+    v = recon.astype(jnp.float32)
+    ops = [jnp.pad(a, pad, mode="edge") for a in (v, *_shifts3(v))]
+    ops += [jnp.pad(a, pad) for a in (labels, cur_labels, ranks)]
+    ebv = jnp.full((1,), eb, jnp.float32)
+    out = pl.pallas_call(
+        functools.partial(_restore3_kernel, nz=nz, ny=ny, nx=nx),
+        grid=grid,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] + [spec] * 10,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(ops[0].shape, jnp.float32),
+        interpret=interpret,
+    )(ebv, *ops)
+    return out[:, :ny, :nx]

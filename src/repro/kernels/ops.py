@@ -155,28 +155,34 @@ def compact_bytes(local: jnp.ndarray, widths: jnp.ndarray, k: int,
 
 def cp_detect(field: jnp.ndarray, backend: Optional[str] = None,
               ty: int = _cpk.DEFAULT_TY, tx: int = _cpk.DEFAULT_TX):
-    """Critical point classification -> int32 labels."""
+    """Critical point classification of a 2-D field (4 neighbours) or a
+    3-D volume (6 neighbours) -> int32 labels."""
     backend = resolve_backend(backend)
     if backend == "jnp":
         return _ref.cp_detect_ref(field)
-    return _cpk.cp_detect(field, ty=ty, tx=tx, interpret=_interp(backend))
+    detect = _cpk.cp_detect3 if field.ndim == 3 else _cpk.cp_detect
+    return detect(field, ty=ty, tx=tx, interpret=_interp(backend))
 
 
 def extrema_restore(recon, labels, cur_labels, ranks, eb: float,
                     backend: Optional[str] = None,
                     ty: int = _exk.DEFAULT_TY, tx: int = _exk.DEFAULT_TX):
-    """Fused lost-extrema restoration -> corrected field."""
+    """Fused lost-extrema restoration of a 2-D field or a 3-D volume ->
+    corrected field."""
     backend = resolve_backend(backend)
     if backend == "jnp":
         return _ref.extrema_restore_ref(recon, labels, cur_labels, ranks, eb)
-    return _exk.extrema_restore(recon, labels, cur_labels, ranks, eb,
+    restore = (_exk.extrema_restore3 if recon.ndim == 3
+               else _exk.extrema_restore)
+    return restore(recon, labels, cur_labels, ranks, eb,
                                 ty=ty, tx=tx, interpret=_interp(backend))
 
 
 def shepard_refine(field: jnp.ndarray, sigma: float = 0.75, radius: int = 2,
                    backend: Optional[str] = None,
                    ty: int = _rbk.DEFAULT_TY, tx: int = _rbk.DEFAULT_TX):
-    """Separable convex RBF estimate (global sigma/radius hot path)."""
+    """Separable convex RBF estimate (global sigma/radius hot path) of a
+    2-D field or a 3-D volume."""
     backend = resolve_backend(backend)
     if backend == "jnp":
         return _ref.shepard_refine_global_ref(field, sigma=sigma, radius=radius)

@@ -75,11 +75,13 @@ def shepard_refine_global_ref(field: jnp.ndarray, sigma=0.75,
 
     Full (non-separable) 7x7 window with global sigma/Chebyshev radius
     (traced scalars, like the kernel), center excluded, edge-replicated —
-    the direct form of eq. (2).
+    the direct form of eq. (2).  A 3-D volume takes the full 7x7x7 window.
     """
     from repro.core.rbf import MAX_RADIUS, _offsets, _window_patches
     f = field.astype(jnp.float32)
     sigma = jnp.asarray(sigma, jnp.float32)
+    if f.ndim == 3:
+        return _shepard3_ref(f, sigma, radius, MAX_RADIUS)
     patches = _window_patches(f, MAX_RADIUS)
     dy, dx = _offsets(MAX_RADIUS)
     dist2 = (dy ** 2 + dx ** 2).astype(jnp.float32)
@@ -88,3 +90,25 @@ def shepard_refine_global_ref(field: jnp.ndarray, sigma=0.75,
              <= jnp.asarray(radius, jnp.int32)) & (dist2 > 0))
     w = jnp.where(keep, w, 0.0)
     return (patches * w[None, None, :]).sum(-1) / w.sum()
+
+
+def _shepard3_ref(f, sigma, radius, r: int) -> jnp.ndarray:
+    """Direct (2r+1)^3-window Shepard estimate of a volume, center
+    excluded, edge-replicated: a weighted sum over every offset of the
+    window (accumulated, so it holds no window-sized stack of copies)."""
+    nz, ny, nx = f.shape
+    pad = jnp.pad(f, r, mode="edge")
+    num = jnp.zeros_like(f)
+    wsum = jnp.float32(0)
+    for dz in range(-r, r + 1):
+        for dy in range(-r, r + 1):
+            for dx in range(-r, r + 1):
+                d2 = dz * dz + dy * dy + dx * dx
+                cheb = max(abs(dz), abs(dy), abs(dx))
+                w = jnp.where((cheb <= jnp.asarray(radius, jnp.int32))
+                              & (d2 > 0),
+                              jnp.exp(-d2 / (2.0 * sigma * sigma)), 0.0)
+                num = num + w * pad[r + dz:r + dz + nz, r + dy:r + dy + ny,
+                                    r + dx:r + dx + nx]
+                wsum = wsum + w
+    return num / wsum

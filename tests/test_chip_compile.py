@@ -8,7 +8,9 @@ the compressor's main path is compiled here for one chip of a described
 202,752 blocks of 32 after tile padding), under ``vmap`` where the batched
 APIs map it, and — for the CD and QZ+LZ kernels — at the KV-page field
 shape of ``serve/paging.py`` (MiniCPM-2B: 36 KV heads x 64 dims x 16
-positions).  Nothing runs: a compile that passes is not a chip run.
+positions).  The 6-neighbour CD, extrema-restore and Shepard kernels of
+3-D volumes are compiled at SDRBench Hurricane-ISABEL's 100x500x500.
+Nothing runs: a compile that passes is not a chip run.
 
 The topology is described inside a module fixture (never at import), so
 every test worker collects the same tests and only the worker that runs
@@ -25,6 +27,7 @@ from repro.kernels import (bitpack_compact, bitpack_pack, cp_detect,
                            extrema_restore, rbf_refine, szp_quant)
 
 ATM = (1800, 3600)
+ISABEL = (100, 500, 500)
 BLOCKS = 202_752            # ceil(1800*3600 / 32) padded to the 256-row tile
 K = 32
 PAGE = (36 * 64, 16)        # serve/paging.py field: (h*dh channels, page)
@@ -83,6 +86,17 @@ CASES = {
     "shepard_atm": (_shepard, [(ATM, f32), ((), f32), ((), i32)]),
     "shepard_atm_batch": (jax.vmap(_shepard),
                           [((N,) + ATM, f32), ((N,), f32), ((N,), i32)]),
+    "cp_detect_isabel": (lambda f: cp_detect.cp_detect3(f, interpret=False),
+                         [(ISABEL, f32)]),
+    "cp_detect_isabel_batch": (
+        jax.vmap(lambda f: cp_detect.cp_detect3(f, interpret=False)),
+        [((N,) + ISABEL, f32)]),
+    "extrema_restore_isabel": (
+        lambda r, lab, cur, rk, eb: extrema_restore.extrema_restore3(
+            r, lab, cur, rk, eb, interpret=False),
+        [(ISABEL, f32), (ISABEL, i32), (ISABEL, i32), (ISABEL, i32),
+         ((), f32)]),
+    "shepard_isabel": (_shepard, [(ISABEL, f32), ((), f32), ((), i32)]),
 }
 
 

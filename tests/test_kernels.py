@@ -72,3 +72,45 @@ def test_shepard_kernel(shape, sigma, radius):
 def test_bad_backend_raises():
     with pytest.raises(ValueError):
         ops.cp_detect(jnp.zeros((4, 4)), backend="bogus")
+
+
+def _volume(shape, seed, ties=False):
+    rng = np.random.default_rng(seed)
+    f = rng.standard_normal(shape).astype(np.float32)
+    if ties:
+        f[::2] = np.round(f[::2] * 2) / 2
+    return jnp.asarray(f)
+
+
+@pytest.mark.parametrize("shape", [(8, 12, 16), (5, 9, 130), (1, 7, 8),
+                                   (3, 1, 5), (2, 2, 2)])
+@pytest.mark.parametrize("ties", [False, True])
+def test_cp_detect_kernel_3d(shape, ties):
+    f = _volume(shape, shape[2], ties)
+    assert bool(jnp.all(ops.cp_detect(f, backend="interpret") == classify(f)))
+
+
+@pytest.mark.parametrize("shape", [(8, 12, 16), (4, 9, 130)])
+@pytest.mark.parametrize("eb", [1e-2, 5e-2])
+def test_extrema_restore_kernel_3d(shape, eb):
+    rng = np.random.default_rng(shape[1])
+    f = _volume(shape, shape[0])
+    recon = quantize_roundtrip(f, eb)
+    labels, cur = classify(f), classify(recon)
+    ranks = jnp.asarray(rng.integers(1, 9, shape).astype(np.int32))
+    out_k = ops.extrema_restore(recon, labels, cur, ranks, eb,
+                                backend="interpret")
+    out_r = ops.extrema_restore(recon, labels, cur, ranks, eb, backend="jnp")
+    assert jnp.array_equal(out_k, out_r), float(jnp.abs(out_k - out_r).max())
+    assert not jnp.array_equal(out_k, recon)
+
+
+@pytest.mark.parametrize("shape", [(8, 12, 16), (9, 10, 11)])
+@pytest.mark.parametrize("sigma,radius", [(0.75, 2), (0.5, 1), (1.0, 3)])
+def test_shepard_kernel_3d(shape, sigma, radius):
+    """The three separable passes equal the direct 7x7x7 window form."""
+    f = _volume(shape, int(sigma * 100))
+    out_k = ops.shepard_refine(f, sigma, radius, backend="interpret")
+    out_r = ops.shepard_refine(f, sigma, radius, backend="jnp")
+    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
+                               rtol=2e-5, atol=2e-5)

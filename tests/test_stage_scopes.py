@@ -28,6 +28,12 @@ def _measure():
                                         backend="jnp")
 
 
+def _measure_3d():
+    vols = jax.random.uniform(jax.random.key(1), (2, 4) + SHAPE)
+    return toposzp._measure_batch.lower(vols, EB, block=32,
+                                        backend="interpret")
+
+
 def _decompress():
     comp = toposzp.toposzp_compress_batch(_fields(), EB, backend="jnp")
     return toposzp._decompress_batch.lower(
@@ -45,9 +51,15 @@ def _szp_pack():
 # program -> (stage names its readers look for, (outer, inner) nestings)
 CASES = {
     "toposzp._compress_measure_batch": (
-        _measure, ["toposzp.stage_detect", "toposzp.stage_rp",
-                   "toposzp.stage_quant"],
-        [("toposzp.stage_detect", "toposzp.stage_rp")]),
+        _measure, ["toposzp.stage_detect", "toposzp.stage_cd",
+                   "toposzp.stage_rp", "toposzp.stage_quant"],
+        [("toposzp.stage_detect", "toposzp.stage_rp"),
+         ("toposzp.stage_detect", "toposzp.stage_cd")]),
+    "toposzp._compress_measure_batch_3d": (
+        _measure_3d, ["toposzp.stage_detect", "toposzp.stage_cd",
+                      "toposzp.stage_rp", "toposzp.stage_quant"],
+        [("toposzp.stage_detect", "toposzp.stage_rp"),
+         ("toposzp.stage_detect", "toposzp.stage_cd")]),
     "toposzp._decompress_batch": (
         _decompress, ["toposzp.stage_decode", "toposzp.stage_decode_md",
                       "toposzp.stage_restore"],
